@@ -40,6 +40,7 @@ ENGINE_WORKER = r"""
 import jax, jax.numpy as jnp, numpy as np, sys
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.specdec import greedy_verify
 
@@ -64,7 +65,7 @@ def greedy(lg):
 fails = []
 for impl, shape, axes in (("ref", (4, 2), ("data", "model")),
                           ("pallas", (4,), ("data",))):
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     tok0 = jax.random.randint(jax.random.PRNGKey(1), (2, 1), 0,
                               cfg.vocab_size)
     # plain autoregressive greedy reference
@@ -119,7 +120,10 @@ sys.exit(1 if fails else 0)
 
 
 def engine_identity_check() -> bool:
+    # eight virtual CPU devices: the forced count exists only on the CPU
+    # backend, so the worker is held there even where a chip is attached
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(
         os.path.dirname(os.path.abspath(__file__)), "..", "src")

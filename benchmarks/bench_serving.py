@@ -85,7 +85,8 @@ def build_engine_backend(args, slots: int, max_prompt: int = 0):
 
         from repro.core.engine import InterleavedEngine, UniformPlan
         cfg = dataclasses.replace(cfg, n_layers=8)
-        mesh = jax.make_mesh((4, n_dev // 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, n_dev // 4), ("data", "model"))
         plan = UniformPlan(4, 2, 0, 1)
         engine = InterleavedEngine(cfg, mesh, plan, n_mb=slots, mb=1,
                                    max_len=max_len)

@@ -7,17 +7,21 @@
   * Poisson traffic through the continuous-batching scheduler + metrics
   * losslessness spot-check vs a single-device decode
 
-Because the engine needs multiple devices, this script re-execs itself with
-a forced host device count if necessary.
+This is a CPU demo: it re-execs itself on the CPU backend with eight
+forced host devices (the forced count exists only there), even where a
+chip is attached. `chip_smoke.py` is the path that runs on the chip.
 
   PYTHONPATH=src python examples/serve_cluster.py
 """
 import os
 import sys
 
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=8")
+_FORCE = "--xla_force_host_platform_device_count=8"
+_FLAGS = os.environ.get("XLA_FLAGS", "")
+if os.environ.get("JAX_PLATFORMS") != "cpu" or _FORCE not in _FLAGS:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if _FORCE not in _FLAGS:
+        os.environ["XLA_FLAGS"] = f"{_FLAGS} {_FORCE}"
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import jax                                                    # noqa: E402
@@ -26,6 +30,7 @@ import numpy as np                                            # noqa: E402
 
 from repro.configs.registry import get_smoke_config           # noqa: E402
 from repro.core.engine import InterleavedEngine, UniformPlan  # noqa: E402
+from repro.launch.mesh import make_mesh                       # noqa: E402
 from repro.models import model as M                           # noqa: E402
 from repro.serving import LimeServer, SamplerConfig           # noqa: E402
 
@@ -34,7 +39,7 @@ def main():
     cfg = get_smoke_config("internlm2-1.8b")
     import dataclasses
     cfg = dataclasses.replace(cfg, n_layers=8)   # 2 segments x 4 stages x 1
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     params = M.init_params(cfg, jax.random.PRNGKey(0))
 
     plan = UniformPlan(n_stage=4, n_seg=2, k_res=0, k_off=1)

@@ -47,9 +47,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import PARTIAL_AUTO_COLLECTIVES_OK, shard_map
 
 from repro.configs.base import Family, ModelConfig
 from repro.core.cost_model import ExecutionPlan, StageAlloc  # noqa: F401
@@ -240,6 +239,12 @@ class InterleavedEngine:
         assert mesh.shape[stage_axis] == plan.n_stage, \
             (mesh.shape, plan.n_stage)
         assert fetch_mode in ("slot", "step")
+        if impl == "pallas" and tuple(mesh.axis_names) != (stage_axis,):
+            raise ValueError(
+                f"impl='pallas' needs a stage-only mesh, not "
+                f"{dict(mesh.shape)}: the step's shard_map leaves the "
+                f"other axes auto, and Mosaic kernels cannot be "
+                f"partitioned there")
         self.cfg, self.mesh, self.plan = cfg, mesh, plan
         self.axis = stage_axis
         self.n_mb, self.mb = n_mb, mb
@@ -262,12 +267,6 @@ class InterleavedEngine:
         self.K = self.k_res_cap + self.k_off_cap
         self.k_res_live = list(self.k_res_b)      # host-side tier boundary
         self.fetch_mode = fetch_mode if self.k_off_cap else "slot"
-        if cfg.family == Family.SSM and not PARTIAL_AUTO_COLLECTIVES_OK:
-            # Old XLA's partitioner fatally asserts compiling the RWKV
-            # family's step-fetch program (manual-subgroup check) even with
-            # replicated inputs; the paper-literal slot fetch is verified
-            # lossless there, so fall back (new JAX keeps 'step').
-            self.fetch_mode = "slot"
         self.S_c = M.kv_cache_len(cfg, max_len, long_mode)
         # paged KV accounting (DESIGN.md §10): the statically-shaped
         # per-slot cache is carved into page_size-token pages owned by a
@@ -287,7 +286,6 @@ class InterleavedEngine:
             self.slot_tables = [BlockTable(page_size)
                                 for _ in range(n_mb * mb)]
             self._paged_pos = 0        # host mirror of glob["pos"]
-        self._stage_ids = jnp.arange(plan.n_stage, dtype=jnp.int32)
         self._refresh_tier_inputs()
         self._fetch = self._build_fetch() if self.fetch_mode == "step" \
             else None
@@ -492,8 +490,7 @@ class InterleavedEngine:
         mesh = self.mesh
         specs = M.build_param_specs(self.cfg)["layers"]
         # manual over EVERY mesh axis: the fetch touches only weights (pod
-        # never shards them), and leaving an axis auto would make this a
-        # partial-auto region whose all_to_all old XLA can't partition
+        # never shards them)
         manual = set(mesh.axis_names)
 
         def off_in_pspec(s):
@@ -580,9 +577,7 @@ class InterleavedEngine:
             chunk it runs at slot `tau`. Stage-sharded leaves arrive via an
             untiled all_to_all on their stage dim; replicated leaves are a
             local gather. 'model'-sharded dims stay sharded throughout
-            (GSPMD auto axes). On old XLA the in-scan all_to_all is emulated
-            with a psum of offset-scattered shards (compat: partial-auto
-            collectives other than psum fatally assert in the partitioner).
+            (GSPMD auto axes).
             """
             if k_off_cap == 0:
                 return None
@@ -599,44 +594,21 @@ class InterleavedEngine:
                     seg = jax.lax.dynamic_index_in_dim(leaf, s_d, 0, False)
                     return jax.lax.dynamic_index_in_dim(seg, d, 0, False)
                 contrib = leaf[s_e, e]        # (n_stage, k_off, *dims_local)
-                if PARTIAL_AUTO_COLLECTIVES_OK:
-                    # untiled all_to_all: axis0 consumed, new n_stage axis
-                    # at the stage-sharded dim; merge it back to full width.
-                    got = jax.lax.all_to_all(contrib, ax, split_axis=0,
-                                             concat_axis=1 + sdim)
-                    # got: (k_off, ..., n_stage, dim/n_stage, ...) at 1+sdim
-                    shp = list(got.shape)
-                    merged = shp[:1 + sdim] \
-                        + [shp[1 + sdim] * shp[2 + sdim]] + shp[3 + sdim:]
-                    return got.reshape(merged)
-                # psum emulation: every stage writes its shard of each
-                # destination's slab at its own offset of the full weight
-                # dim (axis 2+sdim of contrib), disjoint across stages, so
-                # the psum concatenates; each stage then picks its own row.
-                shard = contrib.shape[2 + sdim]
-                full = list(contrib.shape)
-                full[2 + sdim] = shard * n_stage
-                starts = [jnp.int32(0)] * len(full)
-                starts[2 + sdim] = d * shard
-                buf = jax.lax.dynamic_update_slice(
-                    jnp.zeros(tuple(full), contrib.dtype), contrib,
-                    tuple(starts))
-                buf = jax.lax.psum(buf, ax)
-                return jax.lax.dynamic_index_in_dim(buf, d, 0, False)
+                # untiled all_to_all: axis0 consumed, new n_stage axis at
+                # the stage-sharded dim; merge it back to full width.
+                got = jax.lax.all_to_all(contrib, ax, split_axis=0,
+                                         concat_axis=1 + sdim)
+                # got: (k_off, ..., n_stage, dim/n_stage, ...) at 1+sdim
+                shp = list(got.shape)
+                merged = shp[:1 + sdim] \
+                    + [shp[1 + sdim] * shp[2 + sdim]] + shp[3 + sdim:]
+                return got.reshape(merged)
             return jax.tree.map(one, off_local, stage_dims)
 
-        def ring_shift(x, d):
-            """Hand the activation to the next stage. ppermute where the
-            partitioner allows it; else a psum of a one-hot-scattered
-            buffer (stage d writes slot d+1, reads its own slot)."""
-            if PARTIAL_AUTO_COLLECTIVES_OK:
-                return jax.lax.ppermute(
-                    x, ax, [(i, (i + 1) % n_stage) for i in range(n_stage)])
-            buf = jnp.zeros((n_stage,) + x.shape, x.dtype)
-            buf = jax.lax.dynamic_update_index_in_dim(
-                buf, x, (d + 1) % n_stage, 0)
-            buf = jax.lax.psum(buf, ax)
-            return jax.lax.dynamic_index_in_dim(buf, d, 0, False)
+        def ring_shift(x):
+            """Hand the activation to the next stage."""
+            return jax.lax.ppermute(
+                x, ax, [(i, (i + 1) % n_stage) for i in range(n_stage)])
 
         def chunk_params(res_local, fetched, s_d):
             """Assemble the K (padded) layers of the active chunk on this
@@ -655,23 +627,20 @@ class InterleavedEngine:
         step_mode = self.fetch_mode == "step"
 
         def step_fn(resident, offload, shared, cache, glob, tokens,
-                    stage_id, kl, win_tab, real_tab):
+                    kl, win_tab, real_tab):
             """One autoregressive token for all n_mb micro-batches.
             tokens: (n_mb, mb, 1) int32 (replicated). Locals per stage:
             resident (n_seg, 1, k_res_cap, ...); cache (n_seg, 1, K, n_mb,
             mb, ...); offload: fetch_mode='slot' -> the sharded store,
             'step' -> the per-stage restored buffer (1, n_seg, k_off_cap,
-            ...). stage_id: (1,) int32, stage-sharded iota — the stage's
-            own index. Passed in rather than jax.lax.axis_index(ax): in a
-            partial-auto shard_map old XLA lowers axis_index to a
-            PartitionId op its SPMD partitioner rejects.
+            ...).
             kl: (1,) int32 — the stage's LIVE resident count (the dynamic
             tier boundary; retier changes it without recompiling).
             win_tab: (1, n_seg, K) int32 — per-slot attention windows for
             the stage's CURRENT layout (a layer's window moves with it).
             real_tab: (1, n_seg, K) bool — slot holds a real model layer
             (False on dead padding AND grid overhang past cfg.n_layers)."""
-            d = stage_id[0]
+            d = jax.lax.axis_index(ax)
             # dead-slot mask (DESIGN.md §13): resident slots past the live
             # boundary, unfilled headroom, and cap padding are identity —
             # zero weights make them so numerically, the mask makes it
@@ -701,9 +670,8 @@ class InterleavedEngine:
                 else:
                     qpos = pos + jnp.arange(q_len)
                     q_slots = qpos % S_c
-                    # contiguous update (no Scatter — old-XLA partial-auto
-                    # partitioner fatally asserts on it); the verify
-                    # window never wraps (backend caps pos + q_len)
+                    # contiguous update: the verify window never wraps
+                    # (backend caps pos + q_len)
                     pos_ids = jax.lax.dynamic_update_slice(
                         pos_ids, qpos.astype(pos_ids.dtype), (slot,))
 
@@ -815,7 +783,7 @@ class InterleavedEngine:
                     logits_buf)
 
                 # hand activation to the next stage (ring)
-                x_next = ring_shift(x_out, d)
+                x_next = ring_shift(x_out)
                 dbg = (jnp.abs(x_out.astype(jnp.float32)).sum(),
                        c_d, valid.astype(jnp.int32))
                 return (x_next, logits_buf, cache_l,
@@ -841,16 +809,16 @@ class InterleavedEngine:
         if res_only:
             # no offload leg at all: the draft program never sees the
             # streamed store, so XLA cannot schedule a fetch for it
-            def draft_fn(resident, shared, cache, glob, tokens, stage_id,
-                         kl, win_tab, real_tab):
+            def draft_fn(resident, shared, cache, glob, tokens, kl,
+                         win_tab, real_tab):
                 return step_fn(resident, None, shared, cache, glob, tokens,
-                               stage_id, kl, win_tab, real_tab)
+                               kl, win_tab, real_tab)
             in_specs = (jax.tree.map(lambda _: P(None, ax), proto,
                                      is_leaf=is_sds),
                         jax.tree.map(lambda _: P(), self._shared_proto()),
                         {kk: P(None, ax) for kk in self._cache_keys()},
                         {kk: P() for kk in self._glob_keys()},
-                        P(), P(ax), P(ax), P(ax), P(ax))
+                        P(), P(ax), P(ax), P(ax))
             fn = shard_map(draft_fn, mesh=self.mesh, in_specs=in_specs,
                            out_specs=out_specs, axis_names={ax},
                            check_vma=False)
@@ -866,7 +834,7 @@ class InterleavedEngine:
                     jax.tree.map(lambda _: P(), self._shared_proto()),
                     {kk: P(None, ax) for kk in self._cache_keys()},
                     {kk: P() for kk in self._glob_keys()},
-                    P(), P(ax), P(ax), P(ax), P(ax))
+                    P(), P(ax), P(ax), P(ax))
         fn = shard_map(step_fn, mesh=self.mesh, in_specs=in_specs,
                        out_specs=out_specs, axis_names={ax},
                        check_vma=False)
@@ -951,27 +919,16 @@ class InterleavedEngine:
         out["glob"] = glob
         return out
 
-    def _defer_model_sharding(self, fetched):
-        """Old-XLA compat: a fetched buffer whose leaves mix the manual
-        stage dim with at-rest 'model' auto shardings trips the partitioner
-        inside the step (hlo_sharding_util manual-subgroup assert, SSM
-        leaves). Reshard to stage-only between the two programs — an ICI
-        all-gather of the streamed layers' model dims, old JAX only."""
-        if PARTIAL_AUTO_COLLECTIVES_OK:
-            return fetched
-        sh = NamedSharding(self.mesh, P(self.axis))
-        return jax.device_put(fetched, jax.tree.map(lambda _: sh, fetched))
-
     # -- public API ---------------------------------------------------------------
     def decode_step(self, state, tokens):
         """tokens: (n_mb * mb, 1) int32 -> (logits (n_mb*mb, PV), state)."""
         t = tokens.reshape(self.n_mb, self.mb, 1)
         off = state["offload"]
         if self.fetch_mode == "step":
-            off = self._defer_model_sharding(self._fetch(off))
+            off = self._fetch(off)
         logits, cache, glob, dbg = self._step(
             state["resident"], off, state["shared"],
-            state["cache"], state["glob"], t, self._stage_ids,
+            state["cache"], state["glob"], t,
             self._kl_dev, self._win_dev, self._live_dev)
         new_state = dict(state)
         new_state["cache"] = cache
@@ -1029,10 +986,10 @@ class InterleavedEngine:
         t = tokens.reshape(self.n_mb, self.mb, q_len)
         off = state["offload"]
         if self.fetch_mode == "step":
-            off = self._defer_model_sharding(self._fetch(off))
+            off = self._fetch(off)
         logits, cache, glob, dbg = self._steps[q_len](
             state["resident"], off, state["shared"],
-            state["cache"], state["glob"], t, self._stage_ids,
+            state["cache"], state["glob"], t,
             self._kl_dev, self._win_dev, self._live_dev)
         new_state = dict(state)
         new_state["cache"] = cache
@@ -1081,7 +1038,7 @@ class InterleavedEngine:
         t = tokens.reshape(self.n_mb, self.mb, 1)
         logits, cache, glob, dbg = self._steps["draft"](
             state["resident"], state["shared"], state["cache"],
-            state["glob"], t, self._stage_ids, self._kl_dev, self._win_dev,
+            state["glob"], t, self._kl_dev, self._win_dev,
             self._live_dev)
         new_state = dict(state)
         new_state["cache"] = cache
@@ -1279,24 +1236,23 @@ class InterleavedEngine:
         without materializing state."""
         shapes = self._abstract_state()
         t = jax.ShapeDtypeStruct((self.n_mb, self.mb, 1), jnp.int32)
-        sid = jax.ShapeDtypeStruct((self.plan.n_stage,), jnp.int32)
         kl = jax.ShapeDtypeStruct((self.plan.n_stage,), jnp.int32)
         win = jax.ShapeDtypeStruct(
             (self.plan.n_stage, self.plan.n_seg, self.K), jnp.int32)
         real = jax.ShapeDtypeStruct(
             (self.plan.n_stage, self.plan.n_seg, self.K), jnp.bool_)
         if self.fetch_mode == "step":
-            def full(res, off, shared, cache, glob, tokens, stage_id,
-                     kl_in, win_in, real_in):
+            def full(res, off, shared, cache, glob, tokens, kl_in, win_in,
+                     real_in):
                 w = self._fetch(off)
                 return self._step(res, w, shared, cache, glob, tokens,
-                                  stage_id, kl_in, win_in, real_in)
+                                  kl_in, win_in, real_in)
             return jax.jit(full, donate_argnums=(3,)).lower(
                 shapes["resident"], shapes["offload"], shapes["shared"],
-                shapes["cache"], shapes["glob"], t, sid, kl, win, real)
+                shapes["cache"], shapes["glob"], t, kl, win, real)
         return self._step.lower(
             shapes["resident"], shapes["offload"], shapes["shared"],
-            shapes["cache"], shapes["glob"], t, sid, kl, win, real)
+            shapes["cache"], shapes["glob"], t, kl, win, real)
 
     def _abstract_state(self):
         cfg, plan = self.cfg, self.plan

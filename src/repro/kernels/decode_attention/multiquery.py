@@ -31,9 +31,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 # one definition each — the bit-wise kernel-vs-ref contracts depend on
 # every module in this package masking with the same constant
-from repro.kernels import tuning
+from repro.kernels import auto_interpret, tuning
 from repro.kernels.decode_attention.kernel import NEG_INF
-from repro.kernels.decode_attention.ops import GLOBAL_WINDOW, _auto_interpret
+from repro.kernels.decode_attention.ops import GLOBAL_WINDOW
 
 
 # ============================================================================
@@ -140,7 +140,7 @@ def mq_decode_attention(q, k_cache, v_cache, pos_ids, pos, *, window=None,
     pos + i) -> (B, q_len, H, dh). block_k=None consults the tuned table
     (repro.kernels.tuning) at trace time; 512 with none installed."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, Q, H, dh = q.shape
     S_c, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
@@ -350,7 +350,7 @@ def mq_paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
     block_tables: (B, max_pages) int32 (-1 pads); ctx_lens: (B,) int32
     counting tokens incl. the q_len new positions -> (B, q_len, H, dh)."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, Q, H, dh = q.shape
     page_size, KV = k_pool.shape[1], k_pool.shape[2]
     G = H // KV

@@ -11,14 +11,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import tuning
+from repro.kernels import auto_interpret, tuning
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 
 GLOBAL_WINDOW = 2 ** 30
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -28,7 +24,7 @@ def decode_attention(q, k_cache, v_cache, pos_ids, pos, *, window=None,
     pos: int32 scalar -> (B, 1, H, dh). block_k=None consults the tuned
     table (repro.kernels.tuning) at trace time; 512 with none installed."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, _, H, dh = q.shape
     S_c, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV

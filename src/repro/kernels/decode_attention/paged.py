@@ -39,12 +39,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import auto_interpret
+
 NEG_INF = -2.0e30
 GLOBAL_WINDOW = 2 ** 30
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ============================================================================
@@ -219,7 +217,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
     -> (B, 1, H, dh). Pads dh to the 128-lane tile; page_size must be a
     multiple of 8 (f32 sublane tile)."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, _, H, dh = q.shape
     page_size, KV = k_pool.shape[1], k_pool.shape[2]
     G = H // KV

@@ -6,7 +6,7 @@ model-native (B, S, H, dh) layout; this wrapper transposes to the kernel's
 keys leave scores untouched because padded q·k terms are 0; padded kv *rows*
 are masked via skv_real), and slices the result back.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU it compiles
+On CPU the kernel runs in interpret mode; on TPU it compiles
 to Mosaic. ``interpret=None`` auto-detects.
 """
 from __future__ import annotations
@@ -16,14 +16,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import tuning
+from repro.kernels import auto_interpret, tuning
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 GLOBAL_WINDOW = 2 ** 30
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x, axis: int, mult: int):
@@ -45,7 +41,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
     block_q/block_k=None consult the tuned table (repro.kernels.tuning)
     at trace time; (128, 512) with none installed."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, Sq, H, dh = q.shape
     Skv = k.shape[1]
     if window is None:

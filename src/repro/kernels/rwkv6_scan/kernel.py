@@ -38,23 +38,19 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,   # in
     def _load_state():
         state_ref[...] = s0_ref[0, 0]
 
-    u = u_ref[0].astype(jnp.float32)                 # (dh,)
-    r = r_ref[0, 0].astype(jnp.float32)              # (block_t, dh)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    w = w_ref[0, 0].astype(jnp.float32)
+    u = u_ref[0].astype(jnp.float32).T               # (dh, 1)
 
+    # each timestep's row is read straight from the refs: a dynamic slice
+    # of a loaded tile has no Mosaic lowering
     def step(t, S):
-        r_t = jax.lax.dynamic_slice_in_dim(r, t, 1, 0)       # (1, dh)
-        k_t = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)
-        v_t = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)
-        w_t = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)
+        row = (0, 0, pl.ds(t, 1), slice(None))
+        r_t = r_ref[row].astype(jnp.float32)                 # (1, dh)
+        k_t = k_ref[row].astype(jnp.float32)
+        v_t = v_ref[row].astype(jnp.float32)
+        w_t = w_ref[row].astype(jnp.float32)
         a = k_t.T * v_t                                      # (dh, dh)
-        o = r_t @ (S + u[:, None] * a)                       # (1, dh)
-        # int dims spelled as ds(0, 1): bare python ints in a store index
-        # tuple break old Pallas (NDIndexer expects Slice/array indices)
-        pl.store(o_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(t, 1), slice(None)),
-                 o[None, None].astype(o_ref.dtype))
+        o = r_t @ (S + u * a)                                # (1, dh)
+        o_ref[row] = o.astype(o_ref.dtype)
         return w_t.T * S + a
 
     S = jax.lax.fori_loop(0, block_t, step, state_ref[...])
@@ -67,7 +63,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,   # in
 
 def wkv_kernel(r, k, v, w, u, s0, *, block_t: int = 256,
                interpret: bool = False):
-    """r/k/v/w: (B, H, S, dh) [w fp32 decay in (0,1)]; u: (H, dh);
+    """r/k/v/w: (B, H, S, dh) [w fp32 decay in (0,1)]; u: (H, 1, dh);
     s0: (B, H, dh, dh) fp32. S % block_t == 0 (ops.py pads).
     Returns (out (B, H, S, dh) fp32, final state (B, H, dh, dh) fp32)."""
     B, H, S, dh = r.shape
@@ -81,7 +77,7 @@ def wkv_kernel(r, k, v, w, u, s0, *, block_t: int = 256,
         functools.partial(_wkv_kernel, block_t=block_t),
         grid=grid,
         in_specs=[t_spec, t_spec, t_spec, t_spec,
-                  pl.BlockSpec((1, dh), lambda b, h, it: (h, 0)),
+                  pl.BlockSpec((1, 1, dh), lambda b, h, it: (h, 0, 0)),
                   s_spec],
         out_specs=[t_spec, s_spec],
         out_shape=[jax.ShapeDtypeStruct((B, H, S, dh), jnp.float32),

@@ -12,12 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import tuning
+from repro.kernels import auto_interpret, tuning
 from repro.kernels.rwkv6_scan.kernel import wkv_kernel
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -27,7 +23,7 @@ def wkv(r, k, v, w, u, state, *, block_t=None, interpret=None):
     consults the tuned table (repro.kernels.tuning); 256 with none
     installed."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, S, H, dh = r.shape
     block_t = tuning.resolve("rwkv6_scan", S, dh, "block_t", block_t)
     bt = min(block_t, max(S, 8))
@@ -47,6 +43,7 @@ def wkv(r, k, v, w, u, state, *, block_t=None, interpret=None):
     wk = to_kernel(w.astype(jnp.float32), pad_value=1.0)
     uk = jnp.pad(u.astype(jnp.float32), ((0, 0), (0, pad_d))) if pad_d else \
         u.astype(jnp.float32)
+    uk = uk[:, None, :]                               # (H, 1, dh)
     sk = jnp.pad(state, ((0, 0), (0, 0), (0, pad_d), (0, pad_d))) if pad_d \
         else state
 

@@ -34,24 +34,19 @@ def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s0_ref,   # in
     def _load():
         state_ref[...] = s0_ref[0, 0]
 
-    a = a_ref[0]                                  # (1,) this head's A (<0)
-    x = x_ref[0, 0].astype(jnp.float32)           # (block_t, dh)
-    dt = dt_ref[0, 0].astype(jnp.float32)         # (block_t, 1)
-    bmat = b_ref[0].astype(jnp.float32)           # (block_t, N)
-    cmat = c_ref[0].astype(jnp.float32)           # (block_t, N)
+    a = a_ref[0]                                  # (1, 1) this head's A (<0)
 
+    # each timestep's row is read straight from the refs: a dynamic slice
+    # of a loaded tile has no Mosaic lowering
     def step(t, h):
-        x_t = jax.lax.dynamic_slice_in_dim(x, t, 1, 0)        # (1, dh)
-        dt_t = jax.lax.dynamic_slice_in_dim(dt, t, 1, 0)      # (1, 1)
-        b_t = jax.lax.dynamic_slice_in_dim(bmat, t, 1, 0)     # (1, N)
-        c_t = jax.lax.dynamic_slice_in_dim(cmat, t, 1, 0)     # (1, N)
-        decay = jnp.exp(a[0] * dt_t[0, 0])
-        h = decay * h + (dt_t[0, 0] * b_t.T) * x_t            # (N, dh)
-        y = c_t @ h                                           # (1, dh)
-        # int dims spelled as ds(0, 1): bare python ints in a store index
-        # tuple break old Pallas (NDIndexer expects Slice/array indices)
-        pl.store(y_ref, (pl.ds(0, 1), pl.ds(0, 1), pl.ds(t, 1), slice(None)),
-                 y[None, None].astype(y_ref.dtype))
+        x_t = x_ref[0, 0, pl.ds(t, 1), :].astype(jnp.float32)    # (1, dh)
+        dt_t = dt_ref[0, 0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, 1)
+        b_t = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)       # (1, N)
+        c_t = c_ref[0, pl.ds(t, 1), :].astype(jnp.float32)       # (1, N)
+        decay = jnp.exp(a * dt_t)                                # (1, 1)
+        h = decay * h + (dt_t * b_t.T) * x_t                     # (N, dh)
+        y = c_t @ h                                              # (1, dh)
+        y_ref[0, 0, pl.ds(t, 1), :] = y.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, block_t, step, state_ref[...])
@@ -65,7 +60,7 @@ def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, s0_ref,   # in
 def ssm_scan_kernel(x, dt, b, c, a, s0, *, block_t: int = 256,
                     interpret: bool = False):
     """x: (B, H, S, dh); dt: (B, H, S, 1) fp32; b, c: (B, S, N) fp32
-    (shared across heads); a: (H, 1) fp32 negative; s0: (B, H, N, dh) fp32.
+    (shared across heads); a: (H, 1, 1) fp32 negative; s0: (B, H, N, dh) fp32.
     S % block_t == 0. Returns (y (B, H, S, dh) fp32, sT (B, H, N, dh))."""
     B, H, S, dh = x.shape
     N = b.shape[-1]
@@ -81,7 +76,7 @@ def ssm_scan_kernel(x, dt, b, c, a, s0, *, block_t: int = 256,
         functools.partial(_ssm_kernel, block_t=block_t),
         grid=grid,
         in_specs=[t_spec, dt_spec, bc_spec, bc_spec,
-                  pl.BlockSpec((1, 1), lambda bb, h, it: (h, 0)),
+                  pl.BlockSpec((1, 1, 1), lambda bb, h, it: (h, 0, 0)),
                   s_spec],
         out_specs=[t_spec, s_spec],
         out_shape=[jax.ShapeDtypeStruct((B, H, S, dh), jnp.float32),

@@ -11,12 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import tuning
+from repro.kernels import auto_interpret, tuning
 from repro.kernels.ssm_scan.kernel import ssm_scan_kernel
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -26,7 +22,7 @@ def ssm_scan(xh, dt, B_in, C_in, A, state, *, block_t=None,
     block_t=None consults the tuned table (repro.kernels.tuning); 256
     with none installed."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = auto_interpret()
     B, S, H, dh = xh.shape
     N = B_in.shape[-1]
     block_t = tuning.resolve("ssm_scan", S, dh, "block_t", block_t)
@@ -41,7 +37,7 @@ def ssm_scan(xh, dt, B_in, C_in, A, state, *, block_t=None,
         d = jnp.pad(d, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
     bmat = jnp.pad(B_in.astype(jnp.float32), ((0, 0), (0, pad_t), (0, 0)))
     cmat = jnp.pad(C_in.astype(jnp.float32), ((0, 0), (0, pad_t), (0, 0)))
-    a = A.astype(jnp.float32).reshape(H, 1)
+    a = A.astype(jnp.float32).reshape(H, 1, 1)
     s = jnp.pad(state, ((0, 0), (0, 0), (0, 0), (0, pad_d))) if pad_d \
         else state
 

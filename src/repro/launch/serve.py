@@ -1,11 +1,14 @@
 """Serving launcher: LIME-Serve over the interleaved pipeline (DESIGN.md §9).
 
-  # CPU demo (4 virtual stages), bursty traffic:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
-      --stages 4 --pattern bursty --requests 4 --max-new 16
+The engine runs on `--stages x --tp` devices (default: every device as a
+stage); asking for more devices than exist is an error. Only fleet mode
+(`--replicas > 1`) and `--prefix-cache` serve without the engine.
 
-  # Poisson arrivals at 2 req/s through the same engine:
+  # one chip (or one CPU device), Pallas kernels:
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
+      --stages 1 --impl pallas --pattern bursty --requests 4 --max-new 16
+
+  # CPU demo (4 virtual stages), Poisson arrivals at 2 req/s:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \
       --stages 4 --pattern poisson --rate-rps 2 --requests 8
@@ -16,12 +19,17 @@ import argparse
 import json
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--stages", type=int, default=4)
+    ap.add_argument("--stages", type=int, default=None,
+                    help="pipeline stages (default: device count // --tp)")
     ap.add_argument("--tp", type=int, default=1)
+    ap.add_argument("--impl", choices=("ref", "pallas"), default="ref",
+                    help="engine attention: jnp reference or Pallas "
+                         "kernels (compiled on a TPU, interpreted "
+                         "elsewhere)")
     ap.add_argument("--pattern",
                     choices=("sporadic", "bursty", "poisson", "trace",
                              "shared_prefix", "multiturn"),
@@ -113,22 +121,159 @@ def main(argv=None):
                     help="seconds between live dashboard snapshots on "
                          "stdout (0 = off; backend clock, so virtual "
                          "seconds in sim runs)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def build_engine(cfg, args, *, measured=None, log=None):
+    """The InterleavedEngine on args.stages x args.tp devices, plus the
+    OnlinePlanner when --adapt. Returns (engine, planner)."""
+    import jax
+
+    from repro.core.engine import InterleavedEngine, UniformPlan
+    from repro.launch.mesh import make_mesh
+
+    # tp 1: a stage-only mesh, so the step's shard_map is fully manual —
+    # Mosaic kernels cannot be partitioned over an auto 'model' axis
+    if args.tp == 1:
+        mesh = make_mesh((args.stages,), ("data",))
+    else:
+        mesh = make_mesh((args.stages, args.tp), ("data", "model"))
+    n_mb = args.stages if args.pattern != "sporadic" else 1
+    env = None
+    planner = None
+    if args.plan == "hetero" or args.adapt:
+        # per-stage profiles scaled to the model so the offline
+        # scheduler actually offloads (real 16 GB chips would hold a
+        # smoke model outright); --plan hetero varies the memory per
+        # stage, so the emitted ExecutionPlan has unequal splits
+        import dataclasses as _dc
+
+        from repro.core.cost_model import CostEnv, Workload
+        from repro.core.profiles import TPU_V5E, mbps
+        base = cfg.total_params() * 2.0 / args.stages
+        fracs = ([2.0, 1.2, 1.6, 1.0] if args.plan == "hetero"
+                 else [1.5])
+
+        # measured throughputs override the synthetic knobs (memory
+        # stays the enforced budget — DESIGN.md §18)
+        overrides = {}
+        if measured is not None:
+            from repro.tune.profiles import MEASURED_FIELDS
+            overrides = {f: getattr(measured, f)
+                         for f in MEASURED_FIELDS
+                         if getattr(measured, f) > 0}
+
+        def mk_env(scale):
+            devs = [_dc.replace(TPU_V5E, name=f"stage{i}",
+                                mem_bytes=base * scale
+                                * fracs[i % len(fracs)],
+                                **overrides)
+                    for i in range(args.stages)]
+            return CostEnv(devs, mbps(200.0),
+                           Workload(cfg, mb=1, ctx=args.prompt_len,
+                                    n_micro=n_mb))
+        env = mk_env(1.0)
+    if args.plan == "hetero":
+        from repro.core.offline_scheduler import allocate_with_retry
+        r, env, scale = allocate_with_retry(mk_env, cfg.n_layers,
+                                            n_emp=args.max_len)
+        if not r.feasible:
+            raise SystemExit(f"hetero allocation infeasible: {r.reason}")
+        if scale > 1.0 and log is not None:
+            log.info(f"hetero allocation relaxed memory x{scale:.2f} "
+                     f"for feasibility")
+        plan = r.plan
+    else:
+        # pad layers to a chunk grid; one streamed layer per chunk
+        import math
+        n_seg = 2
+        k = math.ceil(cfg.n_layers / (n_seg * args.stages))
+        plan = UniformPlan(args.stages, n_seg, max(k - 1, 0),
+                           1 if k >= 1 else 0)
+    engine = InterleavedEngine(
+        cfg, mesh, plan, n_mb=n_mb, mb=1, max_len=args.max_len,
+        impl=args.impl,
+        retier_headroom=args.retier_headroom if args.adapt else 0)
+    if args.adapt:
+        from repro.core.online_planner import OnlinePlanner
+        planner = OnlinePlanner(env, plan,
+                                horizon_tokens=4 * n_mb * args.max_len)
+    if log is not None:
+        log.info(f"engine: {args.stages} stages x tp{args.tp} on "
+                 f"{jax.devices()[0].platform}, impl={args.impl}, plan "
+                 f"seg={plan.n_seg} k_res={plan.k_res_list} "
+                 f"k_off={plan.k_off_list} adapt={args.adapt}")
+    return engine, planner
+
+
+def resolve_stages(args, n_dev: int) -> None:
+    """Fill the --stages default (every device) and refuse a mesh larger
+    than the devices that exist, or one the Pallas kernels cannot run on."""
+    if args.stages is None:
+        args.stages = max(n_dev // args.tp, 1)
+    if args.impl == "pallas" and args.tp > 1:
+        raise SystemExit("--impl pallas needs --tp 1: Mosaic kernels "
+                         "cannot be partitioned over the 'model' axis")
+    need = args.stages * args.tp
+    if need > n_dev:
+        raise SystemExit(f"--stages {args.stages} x --tp {args.tp} needs "
+                         f"{need} devices; {n_dev} exist")
+
+
+def build_server(cfg, args, *, measured=None, log=None):
+    """The LimeServer main() serves with: seeded random params, the
+    engine (unless fleet mode or --prefix-cache, which serve engine-less
+    by design — one engine cannot back N replicas, and the engine's
+    per-stage cache has no shared page pool), sampler and spec config."""
+    import jax
+
+    from repro.models import model as M
+    from repro.serving import LimeServer, SamplerConfig
+
+    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    engine = planner = None
+    if args.replicas > 1 or args.prefix_cache:
+        if log is not None:
+            log.info("engine-less single-device backend ("
+                     + ("fleet mode" if args.replicas > 1
+                        else "--prefix-cache") + ")")
+    else:
+        engine, planner = build_engine(cfg, args, measured=measured,
+                                       log=log)
+    spec = None
+    if args.spec:
+        from repro.specdec import SpecConfig
+        spec = SpecConfig(k=args.spec_k, draft=args.spec_draft,
+                          seed=args.seed)
+    return LimeServer(cfg, params, engine=engine, max_len=args.max_len,
+                      pattern="sporadic" if args.pattern == "sporadic"
+                      else "bursty",
+                      sampler=SamplerConfig(temperature=args.temperature),
+                      spec=spec,
+                      prefix_cache=args.prefix_cache,
+                      prefill_chunk_tokens=args.prefill_chunk,
+                      page_size=args.page_size,
+                      planner=planner, refit=args.refit)
+
+
+def main(argv=None):
+    args = parse_args(argv)
 
     import jax
+
     from repro.configs.registry import get_config, get_smoke_config
-    from repro.core.engine import InterleavedEngine, UniformPlan
-    from repro.models import model as M
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs.log import get_logger
     from repro.obs.trace import Tracer, set_tracer
-    from repro.serving import (ContinuousBatchingScheduler, LimeServer,
-                               SamplerConfig, SchedulerConfig, cli_arrivals,
-                               requests_from_arrivals, summarize)
+    from repro.serving import (ContinuousBatchingScheduler, SchedulerConfig,
+                               cli_arrivals, requests_from_arrivals,
+                               summarize)
 
     log = get_logger("repro.launch.serve")
+    resolve_stages(args, len(jax.devices()))
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    n_dev = len(jax.devices())
 
     # measured-profile autotune (DESIGN.md §18): load the tune cache and
     # install tuned kernel block configs BEFORE any model code traces
@@ -159,97 +304,11 @@ def main(argv=None):
         elif measured is not None:
             log.info(f"planning from cached measured profile for {dk} "
                      f"(measured {measured.measured_at})")
-    use_engine = n_dev >= args.stages * args.tp and args.stages > 1
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-
-    engine = None
-    planner = None
-    if use_engine:
-        mesh = jax.make_mesh((args.stages, args.tp), ("data", "model"))
-        n_mb = args.stages if args.pattern != "sporadic" else 1
-        env = None
-        if args.plan == "hetero" or args.adapt:
-            # per-stage profiles scaled to the model so the offline
-            # scheduler actually offloads (real 16 GB chips would hold a
-            # smoke model outright); --plan hetero varies the memory per
-            # stage, so the emitted ExecutionPlan has unequal splits
-            import dataclasses as _dc
-
-            from repro.core.cost_model import CostEnv, Workload
-            from repro.core.profiles import TPU_V5E, mbps
-            base = cfg.total_params() * 2.0 / args.stages
-            fracs = ([2.0, 1.2, 1.6, 1.0] if args.plan == "hetero"
-                     else [1.5])
-
-            # measured throughputs override the synthetic knobs (memory
-            # stays the enforced budget — DESIGN.md §18)
-            overrides = {}
-            if measured is not None:
-                from repro.tune.profiles import MEASURED_FIELDS
-                overrides = {f: getattr(measured, f)
-                             for f in MEASURED_FIELDS
-                             if getattr(measured, f) > 0}
-
-            def mk_env(scale):
-                devs = [_dc.replace(TPU_V5E, name=f"stage{i}",
-                                    mem_bytes=base * scale
-                                    * fracs[i % len(fracs)],
-                                    **overrides)
-                        for i in range(args.stages)]
-                return CostEnv(devs, mbps(200.0),
-                               Workload(cfg, mb=1, ctx=args.prompt_len,
-                                        n_micro=n_mb))
-            env = mk_env(1.0)
-        if args.plan == "hetero":
-            from repro.core.offline_scheduler import allocate_with_retry
-            r, env, scale = allocate_with_retry(mk_env, cfg.n_layers,
-                                                n_emp=args.max_len)
-            if not r.feasible:
-                raise SystemExit(f"hetero allocation infeasible: {r.reason}")
-            if scale > 1.0:
-                log.info(f"hetero allocation relaxed memory x{scale:.2f} "
-                         f"for feasibility")
-            plan = r.plan
-            log.info(f"hetero plan: seg={plan.n_seg} "
-                     f"k_res={plan.k_res_list} k_off={plan.k_off_list}")
-        else:
-            # pad layers to a chunk grid; one streamed layer per chunk
-            import math
-            n_seg = 2
-            k = math.ceil(cfg.n_layers / (n_seg * args.stages))
-            plan = UniformPlan(args.stages, n_seg, max(k - 1, 0),
-                               1 if k >= 1 else 0)
-        engine = InterleavedEngine(
-            cfg, mesh, plan, n_mb=n_mb, mb=1, max_len=args.max_len,
-            retier_headroom=args.retier_headroom if args.adapt else 0)
-        if args.adapt:
-            from repro.core.online_planner import OnlinePlanner
-            planner = OnlinePlanner(env, plan,
-                                    horizon_tokens=4 * n_mb * args.max_len)
-        log.info(f"engine: {args.stages} stages x tp{args.tp}, "
-                 f"plan seg={plan.n_seg} chunks k_res={plan.k_res_list} "
-                 f"k_off={plan.k_off_list} adapt={args.adapt}")
-    else:
-        log.info("single-device fallback (no engine)")
-
-    if args.refit and planner is None:
+    srv = build_server(cfg, args, measured=measured, log=log)
+    engine, params, spec = srv.engine, srv.params, srv.spec
+    if args.refit and srv.planner is None:
         log.info("--refit needs an OnlinePlanner to rebuild (engine path "
                  "with --adapt); ignoring")
-
-    spec = None
-    if args.spec:
-        from repro.specdec import SpecConfig
-        spec = SpecConfig(k=args.spec_k, draft=args.spec_draft,
-                          seed=args.seed)
-    srv = LimeServer(cfg, params, engine=engine, max_len=args.max_len,
-                     pattern="sporadic" if args.pattern == "sporadic"
-                     else "bursty",
-                     sampler=SamplerConfig(temperature=args.temperature),
-                     spec=spec,
-                     prefix_cache=args.prefix_cache,
-                     prefill_chunk_tokens=args.prefill_chunk,
-                     page_size=args.page_size,
-                     planner=planner, refit=args.refit)
 
     arrivals = cli_arrivals(args.pattern, args.requests, seed=args.seed,
                             prompt_len=args.prompt_len,
@@ -287,9 +346,6 @@ def main(argv=None):
             # cannot back N independent replicas) behind the router
             from repro.fleet import Fleet, Replica, RouterConfig
             from repro.serving import EngineBackend
-            if engine is not None:
-                log.info("fleet mode: replicas run the single-device "
-                         "fallback backend (engine ignored)")
             reps = [Replica(i, EngineBackend(
                         cfg, params, engine=None, n_slots=srv.slots,
                         max_len=args.max_len, sampler=srv.sampler,

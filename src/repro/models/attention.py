@@ -207,11 +207,9 @@ def attn_decode_multi(params, x, cache_k, cache_v, pos_ids, pos, slots, *,
     posb = pos + jnp.broadcast_to(jnp.arange(Q), (B, Q))
     q = apply_rope(q, posb, rope_theta)
     k = apply_rope(k, posb, rope_theta)
-    # contiguous write at slots[0] (dynamic_update_slice — the one update
-    # op old XLA's partial-auto partitioner accepts inside the engine's
-    # shard_map; Scatter/one-hot variants trip its manual-subgroup
-    # check). Callers guarantee the verify window never wraps the ring:
-    # the serving backend caps q_len so pos + q_len <= max_len.
+    # contiguous write at slots[0]: callers guarantee the verify window
+    # never wraps the ring (the serving backend caps q_len so
+    # pos + q_len <= max_len).
     ck = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),
                                       (0, slots[0], 0, 0))
     cv = jax.lax.dynamic_update_slice(cache_v, v.astype(cache_v.dtype),

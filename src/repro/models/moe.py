@@ -18,9 +18,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.spec import ParamSpec
 from repro.models.modules import mlp, mlp_specs
 
@@ -44,10 +44,9 @@ def moe_specs(d_model: int, n_experts: int, moe_d_ff: int,
 
 def _route(router, x_flat, top_k: int):
     """Returns (weights (T,K) f32, ids (T,K) i32, probs (T,E) f32)."""
-    from repro import compat
     logits = (x_flat.astype(jnp.float32) @ router).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, ids = compat.top_k(probs, top_k)
+    weights, ids = jax.lax.top_k(probs, top_k)
     weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
     return weights, ids, probs
 
@@ -142,12 +141,10 @@ def moe_forward(params, x, *, cfg, mesh=None, capacity_factor: float = 1.25,
 
     if mode == "auto" and mesh is not None and "model" in mesh.shape \
             and E % mesh.shape["model"] == 0:
-        from repro.compat import PARTIAL_AUTO_SHARDING_CONSTRAINT_OK
         out_flat, aux = _moe_local(
             {k: params[k] for k in ("router", "wi_gate", "wi_up", "wo")},
             x_flat, top_k=K, n_experts=E, capacity_factor=capacity_factor,
-            constraint_mesh=(mesh if PARTIAL_AUTO_SHARDING_CONSTRAINT_OK
-                             else None))
+            constraint_mesh=mesh)
         if "shared" in params:
             out_flat = out_flat + mlp(params["shared"], x_flat)
         return out_flat.reshape(B, S, D), aux

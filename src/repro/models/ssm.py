@@ -86,9 +86,7 @@ def wkv_scan_ref(r, k, v, w, u, state):
     uf = u.astype(jnp.float32)
 
     if r.shape[1] == 1:
-        # single decode token: unrolled. A length-1 scan is pure overhead,
-        # and a nested lax.scan inside a partial-auto shard_map (the LIME
-        # engine's slot loop) fatally asserts in old XLA's partitioner.
+        # single decode token: unrolled (a length-1 scan is pure overhead)
         r1, k1, v1, w1 = rf[:, 0], kf[:, 0], vf[:, 0], w[:, 0]
         a = k1[..., :, None] * v1[..., None, :]
         o = jnp.einsum("bhk,bhkd->bhd", r1,

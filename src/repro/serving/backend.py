@@ -931,6 +931,10 @@ class EngineBackend:
             toks = jnp.concatenate(
                 [toks, jnp.tile(toks[-1:], (self.batch_width - toks.shape[0],
                                             1))], 0)
+        if self.engine is not None:
+            # the last epoch's engine state holds a split copy of every
+            # weight: drop it before init_state builds the next one
+            self._state = None
         if self.prefix_cache:
             last = self._start_batch_prefix(reqs, prompts, toks)
         elif self.engine is not None and self.chunk \

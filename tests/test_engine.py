@@ -16,6 +16,7 @@ jnp.bfloat16 = jnp.float32   # fp32 => losslessness must be (near-)exact
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family, AttnKind
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 CASES = {
  "dense": ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
@@ -40,7 +41,7 @@ CASES = {
                              tie_embeddings=True),
 }
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 fails = []
 for name, cfg in CASES.items():
     params = jax.tree.map(
@@ -110,12 +111,13 @@ jnp.bfloat16 = jnp.float32
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig(name="t", family=Family.DENSE, n_layers=8, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                   head_dim=16)
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 n_mb, mb = 2, 4       # mb=4 shards over pod=2 (bursty replicas per pod)
 params = jax.tree.map(
     lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
@@ -164,6 +166,7 @@ jnp.bfloat16 = jnp.float32
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family, AttnKind
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 # sliding-window arch decoding PAST the ring-buffer length (the long_500k
 # serving mode: cache is window-capped, slots wrap via pos_ids)
@@ -171,7 +174,7 @@ cfg = ModelConfig(name="sw", family=Family.DENSE, n_layers=8, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                   head_dim=16, attn_kind=AttnKind.SLIDING, window_size=8)
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 n_mb, mb, max_len = 4, 1, 16
 params = jax.tree.map(
     lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
@@ -206,6 +209,7 @@ jnp.bfloat16 = jnp.float32
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 # paged KV accounting (DESIGN.md §10): seed_cache adoption routed through
 # block-table pages must stay lossless, and slot occupancy must be
@@ -214,7 +218,7 @@ cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                   head_dim=16)
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 params = jax.tree.map(
     lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
     M.init_params(cfg, key))

@@ -15,6 +15,7 @@ import repro.core.engine as E
 from repro.core.cost_model import ExecutionPlan, StageAlloc
 from repro.configs.base import ModelConfig, Family
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
@@ -55,9 +56,9 @@ fails = []
 for impl, shape, axes in (("ref", (4, 2), ("data", "model")),
                           ("pallas", (4,), ("data",))):
     # ref on the partial-auto (stage x model) mesh; pallas on the
-    # stage-only mesh (old XLA's partitioner rejects Pallas calls in
-    # partial-auto regions — the pre-existing engine limitation)
-    mesh = jax.make_mesh(shape, axes)
+    # stage-only mesh (Mosaic kernels cannot be partitioned over an auto
+    # axis)
+    mesh = make_mesh(shape, axes)
     base = decode_tokens(mesh, UNI, impl)
     cases = {
         "hetero": decode_tokens(mesh, HET, impl),
@@ -100,6 +101,7 @@ import repro.core.engine as E
 from repro.core.cost_model import ExecutionPlan, StageAlloc
 from repro.configs.base import ModelConfig, Family
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 from repro.specdec import greedy_verify
 
 cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
@@ -125,7 +127,7 @@ def greedy(lg):
 fails = []
 for impl, shape, axes in (("ref", (4, 2), ("data", "model")),
                           ("pallas", (4,), ("data",))):
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     tok0 = jax.random.randint(key, (2, 1), 0, cfg.vocab_size)
 
     # plain autoregressive greedy reference on the SAME hetero plan

@@ -412,12 +412,13 @@ jnp.bfloat16 = jnp.float32   # fp32 => losslessness must be (near-)exact
 import repro.core.engine as E
 from repro.configs.base import ModelConfig, Family
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 
 cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
                   head_dim=16)
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 params = jax.tree.map(
     lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
     M.init_params(cfg, key))

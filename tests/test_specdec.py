@@ -512,6 +512,7 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ModelConfig, Family
 import repro.core.engine as E
 from repro.models import model as M
+from repro.launch.mesh import make_mesh
 from repro.serving import (ContinuousBatchingScheduler, Request,
                            SchedulerConfig, EngineBackend)
 from repro.specdec import SpecConfig
@@ -522,11 +523,10 @@ cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
 params = M.init_params(cfg, jax.random.PRNGKey(0))
 
 # ref on the partial-auto (stage x model) mesh; pallas on the stage-only
-# mesh (old XLA's partitioner rejects Pallas calls in partial-auto
-# regions — a pre-existing engine limitation, independent of q_len)
+# mesh (Mosaic kernels cannot be partitioned over an auto axis)
 for impl, shape, axes in (("ref", (4, 2), ("data", "model")),
                           ("pallas", (4,), ("data",))):
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     def run(spec):
         eng = E.InterleavedEngine(cfg, mesh, E.UniformPlan(4, 2, 0, 1),
                                   n_mb=2, mb=1, max_len=48, impl=impl)
