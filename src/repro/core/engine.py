@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import re
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -57,6 +58,37 @@ from repro.models import model as M
 from repro.models import spec as pspec
 from repro.obs import trace as tr_ev
 from repro.obs.trace import get_tracer
+
+
+# named scopes of the step program's parts (`lime.<part>`): metadata only,
+# so the compiled code is the same with them or without
+SCOPE_PREFIX = "lime."
+_SCOPE_RE = re.compile(r"(?:^|/)" + re.escape(SCOPE_PREFIX) + r"(\w+)")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s(.*)$", re.M)
+_OP_NAME_RE = re.compile(r"op_name=\"([^\"]*)\"")
+
+
+def _scope(part: str):
+    return jax.named_scope(SCOPE_PREFIX + part)
+
+
+def hlo_scopes(hlo_text: str, whole: Optional[str] = None):
+    """(module name, {HLO instruction name: innermost `lime.*` part}) of a
+    compiled program's text, read from each instruction's op_name
+    metadata; instructions outside every part are left out. With
+    `whole`, the program is that one part, and every instruction maps to
+    it (the copies XLA inserts carry no metadata)."""
+    head = re.match(r"\s*HloModule\s+([^\s,]+)", hlo_text)
+    ops = {}
+    for name, rest in _INSTR_RE.findall(hlo_text):
+        if whole is not None:
+            ops[name] = SCOPE_PREFIX + whole
+            continue
+        op_name = _OP_NAME_RE.search(rest)
+        parts = _SCOPE_RE.findall(op_name.group(1)) if op_name else []
+        if parts:
+            ops[name] = SCOPE_PREFIX + parts[-1]
+    return (head.group(1) if head else ""), ops
 
 
 # cache entries stacked on the layer dim (everything else — pos, pos_ids —
@@ -294,6 +326,7 @@ class InterleavedEngine:
         # built lazily on first use
         self._steps: Dict[Any, Any] = {1: self._build_step(1)}
         self._step = self._steps[1]
+        self._scoped = set()       # programs with an engine.scopes event
 
     # -- tier boundary (retier) inputs -----------------------------------------
     def _refresh_tier_inputs(self) -> None:
@@ -336,10 +369,14 @@ class InterleavedEngine:
         engine state with resident/offloaded splits + per-stage caches.
         Respects the live tier boundary: layers demoted by earlier retier
         calls land in the streamed store."""
-        cfg, plan = self.cfg, self.plan
         assert "dense_layers" not in params, \
             "engine expects a homogeneous stack; fold dense layers via " \
             "configs with first_dense_layers=0 or pad (see tests)"
+        with tr_ev.span(tr_ev.ENGINE_INIT_STATE, track=tr_ev.TRACK_PIPELINE):
+            return self._init_state(params)
+
+    def _init_state(self, params) -> Dict[str, Any]:
+        cfg, plan = self.cfg, self.plan
         res, off = split_layer_stack(params["layers"], plan,
                                      headroom=self.H,
                                      k_res_live=self.k_res_live)
@@ -512,6 +549,7 @@ class InterleavedEngine:
             lambda s: stage_shard_dim(s.shape[1:], n_stage), specs,
             is_leaf=pspec.is_spec)
 
+        @_scope("restore")
         def fetch_fn(off):
             def one(leaf, sdim):
                 # leaf local: (n_seg, n_stage, k_off, *local_dims)
@@ -572,6 +610,7 @@ class InterleavedEngine:
             lambda s: stage_shard_dim(s.shape[1:], n_stage), layer_shapes,
             is_leaf=is_sds)
 
+        @_scope("restore")
         def fetch_chunk_weights(off_local, tau, d):
             """all_to_all restore of each stage's streamed layers for the
             chunk it runs at slot `tau`. Stage-sharded leaves arrive via an
@@ -605,11 +644,13 @@ class InterleavedEngine:
                 return got.reshape(merged)
             return jax.tree.map(one, off_local, stage_dims)
 
+        @_scope("ring_shift")
         def ring_shift(x):
             """Hand the activation to the next stage."""
             return jax.lax.ppermute(
                 x, ax, [(i, (i + 1) % n_stage) for i in range(n_stage)])
 
+        @_scope("chunk_params")
         def chunk_params(res_local, fetched, s_d):
             """Assemble the K (padded) layers of the active chunk on this
             stage: resident cap first, then the streamed store (headroom +
@@ -696,9 +737,10 @@ class InterleavedEngine:
                     nxt = cur = None
                 elif step_mode:
                     nxt = None
-                    cur = None if k_off_cap == 0 else jax.tree.map(
-                        lambda w: jax.lax.dynamic_index_in_dim(
-                            w[0], s_d, 0, False), offload)
+                    with _scope("restore"):
+                        cur = None if k_off_cap == 0 else jax.tree.map(
+                            lambda w: jax.lax.dynamic_index_in_dim(
+                                w[0], s_d, 0, False), offload)
                 else:
                     nxt = fetch_chunk_weights(offload, tau + 1, d) \
                         if prefetch else None
@@ -712,14 +754,15 @@ class InterleavedEngine:
                                  x)
 
                 p_chunk = chunk_params(resident, cur, s_d)
-                cache_chunk = {kk: jax.lax.dynamic_index_in_dim(
-                    v[:, 0], s_d, 0, keepdims=False) for kk, v in
-                    cache_l.items()}      # (k, n_mb, mb, ...)
-                cache_mb = {kk: jax.lax.dynamic_index_in_dim(
-                    v, jnp.clip(m_d, 0, n_mb - 1), 1, keepdims=False)
-                    for kk, v in cache_chunk.items()}   # (k, mb, ...)
-                if res_only:
-                    cache_mb = {kk: v[:KC] for kk, v in cache_mb.items()}
+                with _scope("cache_read"):
+                    cache_chunk = {kk: jax.lax.dynamic_index_in_dim(
+                        v[:, 0], s_d, 0, keepdims=False) for kk, v in
+                        cache_l.items()}      # (k, n_mb, mb, ...)
+                    cache_mb = {kk: jax.lax.dynamic_index_in_dim(
+                        v, jnp.clip(m_d, 0, n_mb - 1), 1, keepdims=False)
+                        for kk, v in cache_chunk.items()}   # (k, mb, ...)
+                    if res_only:
+                        cache_mb = {kk: v[:KC] for kk, v in cache_mb.items()}
 
                 moe_mesh = self.mesh if (cfg.family == Family.MOE
                                          and "model" in self.mesh.shape) \
@@ -739,14 +782,15 @@ class InterleavedEngine:
                     return (jnp.where(alive, x_new, x_prev),
                             jnp.where(alive, aux_new, aux_prev)), ys_l
 
-                xs = {"p": p_chunk,
-                      "window": jax.lax.dynamic_index_in_dim(win_d, s_d, 0,
-                                                             False),
-                      "live": live_d & jax.lax.dynamic_index_in_dim(
-                          real_d, s_d, 0, False)}
-                xs.update(cache_mb)
-                (x_out, _), ys = jax.lax.scan(body, (x_in, jnp.float32(0.)),
-                                              xs)
+                with _scope("layers"):
+                    xs = {"p": p_chunk,
+                          "window": jax.lax.dynamic_index_in_dim(
+                              win_d, s_d, 0, False),
+                          "live": live_d & jax.lax.dynamic_index_in_dim(
+                              real_d, s_d, 0, False)}
+                    xs.update(cache_mb)
+                    (x_out, _), ys = jax.lax.scan(
+                        body, (x_in, jnp.float32(0.)), xs)
 
                 # commit cache only when valid
                 m_c = jnp.clip(m_d, 0, n_mb - 1)
@@ -769,18 +813,21 @@ class InterleavedEngine:
                     return jax.lax.dynamic_update_index_in_dim(
                         old, cur_s[None], s_d, 0)
                 cache_l = dict(cache_l)      # keep read-only keys (xk/xv)
-                cache_l.update({kk: commit(cache_l[kk], ys[kk])
-                                for kk in ys})
+                with _scope("cache_commit"):
+                    cache_l.update({kk: commit(cache_l[kk], ys[kk])
+                                    for kk in ys})
 
                 # last chunk: unembed and stash logits
-                is_last = valid & (c_d == C - 1)
-                xn = M.rms_norm(x_out, shared["final_norm"], cfg.norm_eps)
-                lg = M.unembed(shared, xn).astype(jnp.float32)
-                logits_buf = jnp.where(
-                    is_last,
-                    jax.lax.dynamic_update_index_in_dim(
-                        logits_buf, lg, jnp.clip(m_d, 0, n_mb - 1), 0),
-                    logits_buf)
+                with _scope("unembed"):
+                    is_last = valid & (c_d == C - 1)
+                    xn = M.rms_norm(x_out, shared["final_norm"],
+                                    cfg.norm_eps)
+                    lg = M.unembed(shared, xn).astype(jnp.float32)
+                    logits_buf = jnp.where(
+                        is_last,
+                        jax.lax.dynamic_update_index_in_dim(
+                            logits_buf, lg, jnp.clip(m_d, 0, n_mb - 1), 0),
+                        logits_buf)
 
                 # hand activation to the next stage (ring)
                 x_next = ring_shift(x_out)
@@ -842,6 +889,20 @@ class InterleavedEngine:
         # would otherwise double-buffer them (kimi-k2: +4.2 GB/chip peak)
         return jax.jit(fn, donate_argnums=(3,))
 
+    def _note_scopes(self, key, program, *args, whole=None) -> None:
+        """With a tracer installed, one `engine.scopes` event per compiled
+        program: its module name and each HLO instruction's `lime.*`
+        part, from the compiled text (a compile-cache hit after the first
+        call). `whole` names the part that is the whole program."""
+        tr = get_tracer()
+        if tr is None or key in self._scoped:
+            return
+        self._scoped.add(key)
+        module, ops = hlo_scopes(program.lower(*args).compile().as_text(),
+                                 whole)
+        tr.instant(tr_ev.ENGINE_SCOPES, track=tr_ev.TRACK_ENGINE,
+                   args={"module": module, "program": str(key), "ops": ops})
+
     # -- paged slot accounting (DESIGN.md §10) -----------------------------------
     def _paged_seed_slots(self, ctx: int) -> None:
         """(Re)build every slot's block table to hold `ctx` tokens."""
@@ -893,12 +954,10 @@ class InterleavedEngine:
         back before the per-stage reshape, so the table indirection (not a
         contiguous memcpy) is what carries the bytes, and slot occupancy
         is page-granular from the first decode step."""
-        tr = get_tracer()
-        if tr is not None:
-            tr.instant(tr_ev.ENGINE_SEED, track=tr_ev.TRACK_ENGINE,
-                       args={"pos": int(cache["pos"]),
-                             "paged": self.paged})
-        plan = self.plan
+        with tr_ev.span(tr_ev.ENGINE_SEED_CACHE, track=tr_ev.TRACK_PIPELINE):
+            return self._seed_cache(state, cache)
+
+    def _seed_cache(self, state, cache) -> Dict[str, Any]:
         paged_ctx = int(cache["pos"]) if self.paged else 0
         if self.paged:
             self._paged_pos = paged_ctx
@@ -924,12 +983,17 @@ class InterleavedEngine:
         """tokens: (n_mb * mb, 1) int32 -> (logits (n_mb*mb, PV), state)."""
         t = tokens.reshape(self.n_mb, self.mb, 1)
         off = state["offload"]
+        pipe = tr_ev.TRACK_PIPELINE
         if self.fetch_mode == "step":
-            off = self._fetch(off)
-        logits, cache, glob, dbg = self._step(
-            state["resident"], off, state["shared"],
-            state["cache"], state["glob"], t,
-            self._kl_dev, self._win_dev, self._live_dev)
+            self._note_scopes("fetch", self._fetch, off, whole="restore")
+            with tr_ev.span(tr_ev.ENGINE_FETCH, track=pipe):
+                off = self._fetch(off)
+        args = (state["resident"], off, state["shared"], state["cache"],
+                state["glob"], t, self._kl_dev, self._win_dev,
+                self._live_dev)
+        self._note_scopes(1, self._steps[1], *args)
+        with tr_ev.span(tr_ev.ENGINE_STEP, track=pipe):
+            logits, cache, glob, dbg = self._step(*args)
         new_state = dict(state)
         new_state["cache"] = cache
         new_state["glob"] = glob
@@ -948,10 +1012,10 @@ class InterleavedEngine:
         for every occupancy level (recompiling per occupancy would defeat
         continuous batching).
         """
-        tr = get_tracer()
-        if tr is not None:
-            tr.instant(tr_ev.ENGINE_DECODE, track=tr_ev.TRACK_ENGINE,
-                       args={"live": int(np.asarray(active, bool).sum())})
+        with tr_ev.span(tr_ev.ENGINE_DISPATCH, track=tr_ev.TRACK_PIPELINE):
+            return self._decode_requests(state, tokens, active)
+
+    def _decode_requests(self, state, tokens, active):
         if self.paged:
             # page-granular occupancy: live slots grow one token (a new
             # page every page_size steps); released slots hold nothing.
@@ -986,11 +1050,13 @@ class InterleavedEngine:
         t = tokens.reshape(self.n_mb, self.mb, q_len)
         off = state["offload"]
         if self.fetch_mode == "step":
+            self._note_scopes("fetch", self._fetch, off, whole="restore")
             off = self._fetch(off)
-        logits, cache, glob, dbg = self._steps[q_len](
-            state["resident"], off, state["shared"],
-            state["cache"], state["glob"], t,
-            self._kl_dev, self._win_dev, self._live_dev)
+        args = (state["resident"], off, state["shared"], state["cache"],
+                state["glob"], t, self._kl_dev, self._win_dev,
+                self._live_dev)
+        self._note_scopes(q_len, self._steps[q_len], *args)
+        logits, cache, glob, dbg = self._steps[q_len](*args)
         new_state = dict(state)
         new_state["cache"] = cache
         new_state["glob"] = glob
@@ -1036,10 +1102,11 @@ class InterleavedEngine:
         if "draft" not in self._steps:
             self._steps["draft"] = self._build_step(1, resident_only=True)
         t = tokens.reshape(self.n_mb, self.mb, 1)
-        logits, cache, glob, dbg = self._steps["draft"](
-            state["resident"], state["shared"], state["cache"],
-            state["glob"], t, self._kl_dev, self._win_dev,
-            self._live_dev)
+        args = (state["resident"], state["shared"], state["cache"],
+                state["glob"], t, self._kl_dev, self._win_dev,
+                self._live_dev)
+        self._note_scopes("draft", self._steps["draft"], *args)
+        logits, cache, glob, dbg = self._steps["draft"](*args)
         new_state = dict(state)
         new_state["cache"] = cache
         new_state["glob"] = glob

@@ -22,7 +22,16 @@ instead of summarizing them away. Design constraints, in order:
                   engine runs render identically in Perfetto. The
                   scheduler binds `tracer.clock` to `backend.now` at
                   construction; sites without a better clock call
-                  `tracer.now()`.
+                  `tracer.now()`. A `clock.sync` instant pairs that clock
+                  with `time.time_ns()`, the clock jax.profiler stamps
+                  host events with, at the bind and whenever the backend
+                  skews its clock (`EngineBackend.advance_to`): a ring
+                  time t maps to wall ns as t_ns + (t - t_sync) * 1e9
+                  from the last sync before it.
+  profiler spans  `Tracer.span` also opens a jax.profiler.TraceAnnotation
+                  of the same name, so any profiler session (xprof
+                  included) shows the program's spans beside the device
+                  ops. Off, that is a TraceMe no-op.
 
 Events are plain tuples (EVT_* index constants below), not objects: the
 hot path allocates one tuple and one deque append per event.
@@ -41,7 +50,24 @@ and step / substrate internals (tracks "pipeline", "dev:<i>",
   prefix.hit  prefix.insert  prefix.evict
   retier  retier.reclaim  planner.fired
   engine.prefill  engine.decode  engine.verify  engine.draft
-  engine.seed  engine.retier
+  engine.retier  engine.init_state  engine.seed_cache  engine.dispatch
+  engine.fetch  engine.step  engine.scopes  backend.prefill
+  backend.sample  backend.sync  sched.step  hbm.bytes_in_use  clock.sync
+
+The chip path's spans nest, per scheduler step (track "pipeline"):
+
+  sched.step
+    engine.prefill (EngineBackend.start_batch)
+      backend.prefill  engine.init_state  engine.seed_cache
+      backend.sample  backend.sync
+    engine.decode (EngineBackend.decode_active)
+      engine.dispatch (InterleavedEngine.decode_requests)
+        engine.fetch  engine.step
+      backend.sample  backend.sync
+
+`engine.scopes` (one per compiled step program) maps each HLO instruction
+of the program to the innermost `lime.<part>` named scope of the engine's
+step (core/engine.py), so device ops in a profile can be read by part.
 
 Phases follow the Chrome trace-event format (`ph`): "i" instant,
 "X" complete (ts + dur), "B"/"E" begin/end, "C" counter.
@@ -50,7 +76,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Callable, List, Optional, Tuple
 
 # tuple layout of one event (kept flat for allocation cost)
@@ -95,8 +121,19 @@ ENGINE_PREFILL = "engine.prefill"
 ENGINE_DECODE = "engine.decode"
 ENGINE_VERIFY = "engine.verify"
 ENGINE_DRAFT = "engine.draft"
-ENGINE_SEED = "engine.seed"
 ENGINE_RETIER = "engine.retier"
+ENGINE_INIT_STATE = "engine.init_state"
+ENGINE_SEED_CACHE = "engine.seed_cache"
+ENGINE_DISPATCH = "engine.dispatch"
+ENGINE_FETCH = "engine.fetch"
+ENGINE_STEP = "engine.step"
+ENGINE_SCOPES = "engine.scopes"
+BACKEND_PREFILL = "backend.prefill"
+BACKEND_SAMPLE = "backend.sample"
+BACKEND_SYNC = "backend.sync"
+SCHED_STEP = "sched.step"
+HBM_BYTES_IN_USE = "hbm.bytes_in_use"
+CLOCK_SYNC = "clock.sync"
 # fleet router (DESIGN.md §16; track "router")
 FLEET_ROUTE = "fleet.route"
 FLEET_SPILLOVER = "fleet.spillover"
@@ -197,14 +234,22 @@ class Tracer:
     @contextmanager
     def span(self, name: str, *, track: str = TRACK_SCHED,
              args: Optional[dict] = None):
-        """Wall-span context manager on the tracer clock (engine paths);
-        discrete-event code passes explicit ts/dur via complete()."""
-        t0 = self.clock()
-        try:
-            yield self
-        finally:
-            self.complete(name, ts=t0, dur=self.clock() - t0,
-                          track=track, args=args)
+        """Wall-span context manager on the tracer clock (engine paths),
+        mirrored into any running profiler session as a TraceAnnotation
+        of the same name; discrete-event code passes explicit ts/dur via
+        complete()."""
+        with _annotation(name):
+            t0 = self.clock()
+            try:
+                yield self
+            finally:
+                self.complete(name, ts=t0, dur=self.clock() - t0,
+                              track=track, args=args)
+
+    def clock_sync(self) -> None:
+        """Pair the tracer clock with the profiler's (`time.time_ns`)."""
+        self.instant(CLOCK_SYNC, track=TRACK_SCHED,
+                     args={"time_ns": time.time_ns()})
 
     # -- reading -----------------------------------------------------------------
     def events(self) -> List[Event]:
@@ -239,6 +284,21 @@ _tracer: Optional[Tracer] = None
 def get_tracer() -> Optional[Tracer]:
     """The installed tracer, or None (tracing off — the common case)."""
     return _tracer
+
+
+NULL_SPAN = nullcontext()
+
+
+def span(name: str, *, track: str = TRACK_SCHED):
+    """The installed tracer's span, or the shared no-op context when
+    tracing is off (sites whose span carries no args)."""
+    tr = _tracer
+    return NULL_SPAN if tr is None else tr.span(name, track=track)
+
+
+def _annotation(name: str):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:

@@ -618,6 +618,9 @@ class EngineBackend:
         cur = self.now()
         if t > cur:
             self._skew += t - cur
+            tr = get_tracer()
+            if tr is not None:
+                tr.clock_sync()
 
     # -- capacity ----------------------------------------------------------------
     def kv_budget_tokens(self) -> Optional[int]:
@@ -918,15 +921,44 @@ class EngineBackend:
                 "prefill_tokens_saved": self._saved_tokens}
 
     # -- serving hooks -----------------------------------------------------------
+    def _note_hbm(self) -> None:
+        """`hbm.bytes_in_use` counter (tracing on only): bytes in use and
+        the peak so far on the fullest device, where the backend reports
+        them."""
+        tr = get_tracer()
+        if tr is None:
+            return
+        import jax
+        devs = self.engine.mesh.devices.flat if self.engine is not None \
+            else jax.local_devices()[:1]
+        stats = [d.memory_stats() for d in devs]
+        if not all(stats):
+            return
+        tr.counter(tr_ev.HBM_BYTES_IN_USE, track=tr_ev.TRACK_PIPELINE,
+                   bytes_in_use=max(m.get("bytes_in_use", 0) for m in stats),
+                   peak_bytes=max(m.get("peak_bytes_in_use", 0)
+                                  for m in stats))
+
     def start_batch(self, reqs: Sequence) -> List[Optional[int]]:
+        tr = get_tracer()
+        if tr is None:
+            return self._start_batch(reqs, None)
+        args = {"batch": len(reqs)}
+        with tr.span(tr_ev.ENGINE_PREFILL, track=tr_ev.TRACK_PIPELINE,
+                     args=args):
+            self._note_hbm()
+            return self._start_batch(reqs, args)
+
+    def _start_batch(self, reqs, trace_args) -> List[Optional[int]]:
         import jax.numpy as jnp
 
         from repro.models import model as M
 
-        tr = get_tracer()
-        t0 = tr.now() if tr is not None else 0.0
+        pipe = tr_ev.TRACK_PIPELINE
         prompts = [self._materialize_prompt(r) for r in reqs]
         toks = self._pad_prompts(prompts)
+        if trace_args is not None:
+            trace_args["span"] = int(toks.shape[1])
         if toks.shape[0] < self.batch_width:  # pad batch with replicas
             toks = jnp.concatenate(
                 [toks, jnp.tile(toks[-1:], (self.batch_width - toks.shape[0],
@@ -947,12 +979,16 @@ class EngineBackend:
                 state, toks, chunk=self.chunk)
             last = lg[:, -1]
         else:
-            cache = M.init_cache(self.cfg, toks.shape[0], self.max_len)
-            logits, cache = self._prefill(self.params, toks, cache)
-            last = logits[:, -1]
+            with tr_ev.span(tr_ev.BACKEND_PREFILL, track=pipe):
+                cache = M.init_cache(self.cfg, toks.shape[0], self.max_len)
+                logits, cache = self._prefill(self.params, toks, cache)
+                last = logits[:, -1]
+            self._note_hbm()
             if self.engine is not None:
                 state = self.engine.init_state(self.params)
+                self._note_hbm()
                 self._state = self.engine.seed_cache(state, cache)
+                self._note_hbm()
             elif self.paged:
                 from repro.kvcache.paged_decode import PagedDecodeCache
                 if self._paged_cache is not None:
@@ -964,12 +1000,9 @@ class EngineBackend:
                 self._state = None
             else:
                 self._state = cache
-        tok = self._sample(last)
-        if tr is not None:
-            tr.complete(tr_ev.ENGINE_PREFILL, ts=t0, dur=tr.now() - t0,
-                        track=tr_ev.TRACK_PIPELINE,
-                        args={"batch": len(reqs),
-                              "span": int(toks.shape[1])})
+        with tr_ev.span(tr_ev.BACKEND_SAMPLE, track=pipe):
+            tok = self._sample(last)
+        self._note_hbm()
         if self.prefix_cache:
             for slot in range(len(reqs)):
                 self._slot_out[slot].append(int(tok[slot]))
@@ -986,10 +1019,12 @@ class EngineBackend:
                 # drafts see the real (unpadded) prompt + first token
                 self._ctl.begin(slot, list(int(t) for t in p)
                                 + [int(tok[slot])])
-        return [int(tok[slot]) for slot in range(len(reqs))]
+        with tr_ev.span(tr_ev.BACKEND_SYNC, track=pipe):
+            first = [int(tok[slot]) for slot in range(len(reqs))]
+        self._note_hbm()
+        return first
 
     def decode_active(self, slots: Sequence[int]):
-        import jax.numpy as jnp
         # speculative round when a draft fits before the cache/ring end
         # (the last position is reserved for the committed-token write)
         if self.spec is not None:
@@ -1000,7 +1035,15 @@ class EngineBackend:
             if slots and k >= 1:
                 return self._decode_active_spec(slots, k)
         tr = get_tracer()
-        t0 = tr.now() if tr is not None else 0.0
+        if tr is None:
+            return self._decode_once(slots)
+        with tr.span(tr_ev.ENGINE_DECODE, track=tr_ev.TRACK_PIPELINE,
+                     args={"slots": len(slots)}):
+            return self._decode_once(slots)
+
+    def _decode_once(self, slots: Sequence[int]):
+        import jax.numpy as jnp
+        pipe = tr_ev.TRACK_PIPELINE
         active = np.zeros(self.batch_width, bool)
         for s in slots:
             active[s] = True
@@ -1016,7 +1059,8 @@ class EngineBackend:
                                            self._cur)
             if lg.ndim == 3:
                 lg = lg[:, 0]
-        tok = self._sample(lg)
+        with tr_ev.span(tr_ev.BACKEND_SAMPLE, track=pipe):
+            tok = self._sample(lg)
         if self.prefix_cache:
             for s in slots:
                 self._slot_out[s].append(int(tok[s]))
@@ -1027,11 +1071,8 @@ class EngineBackend:
         # freed slots keep replaying their last token as pipeline padding
         self._cur = jnp.where(jnp.asarray(active)[:, None], tok[:, None],
                               self._cur)
-        if tr is not None:
-            tr.complete(tr_ev.ENGINE_DECODE, ts=t0, dur=tr.now() - t0,
-                        track=tr_ev.TRACK_PIPELINE,
-                        args={"slots": len(slots)})
-        return {s: int(tok[s]) for s in slots}
+        with tr_ev.span(tr_ev.BACKEND_SYNC, track=pipe):
+            return {s: int(tok[s]) for s in slots}
 
     def _decode_active_spec(self, slots: Sequence[int], k: int):
         """One speculative round: propose k per live slot, verify all of

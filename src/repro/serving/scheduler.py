@@ -230,6 +230,7 @@ class ContinuousBatchingScheduler:
         self._tr = get_tracer()
         if self._tr is not None:
             self._tr.clock = backend.now
+            self._tr.clock_sync()
         # online SLO engine (DESIGN.md §17): attach_slo installs one;
         # finishes and rejections feed its burn-rate windows, and its
         # pressure signal reaches the backend's OnlinePlanner
@@ -695,6 +696,12 @@ class ContinuousBatchingScheduler:
         """One scheduler iteration: intake due arrivals, then either form
         an admission batch or run one decode round. Returns False when
         the run is drained (nothing pending, queued, or live)."""
+        if self._tr is None:
+            return self._step()
+        with self._tr.span(tr_ev.SCHED_STEP, track=tr_ev.TRACK_PIPELINE):
+            return self._step()
+
+    def _step(self) -> bool:
         pending, queue = self._pending, self._q
         suspended, active = self._susp, self._active
         tr = self._tr

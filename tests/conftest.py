@@ -24,6 +24,17 @@ import jax.numpy as jnp
 import pytest
 
 
+@pytest.fixture(autouse=True)
+def _tracer_restored():
+    """A test that installs a process tracer (importing
+    chipbench/program_trace.py does) leaves the next test the one it
+    found: tracing stays off unless a test turns it on."""
+    from repro.obs.trace import get_tracer, set_tracer
+    prev = get_tracer()
+    yield
+    set_tracer(prev)
+
+
 @pytest.fixture(scope="session")
 def rng():
     return jax.random.PRNGKey(0)

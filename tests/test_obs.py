@@ -401,3 +401,104 @@ def test_engine_fallback_serve_traced():
     assert tr_ev.ENGINE_DECODE in names
     assert tr_ev.REQ_SPAN in names
     assert validate_chrome(to_chrome(tr)) == []
+
+
+# ----------------------------------------------------------------------------
+# one clock with the profiler: clock.sync, profiler annotations, zero cost
+# ----------------------------------------------------------------------------
+def _syncs(tr):
+    return [e for e in tr.events() if e[EVT_NAME] == tr_ev.CLOCK_SYNC]
+
+
+def test_clock_sync_at_bind_and_after_a_skew(smoke_model):
+    """The scheduler's bind pairs the backend clock with time.time_ns;
+    EngineBackend.advance_to pairs them again only when it moves the
+    skew."""
+    import time
+
+    from repro.serving import EngineBackend, SamplerConfig
+    cfg, params = smoke_model
+    with tracing() as tr:
+        be = EngineBackend(cfg, params, engine=None, n_slots=1, max_len=32,
+                           sampler=SamplerConfig())
+        before = time.time_ns()
+        ContinuousBatchingScheduler(be, SchedulerConfig())
+        after = time.time_ns()
+        (sync,) = _syncs(tr)
+        assert before <= sync[EVT_ARGS]["time_ns"] <= after
+        assert sync[EVT_TS] == pytest.approx(be.now(), abs=1.0)
+        be.advance_to(be.now() - 1.0)             # no skew: no sync
+        assert len(_syncs(tr)) == 1
+        be.advance_to(be.now() + 5.0)
+        syncs = _syncs(tr)
+    assert len(syncs) == 2
+    # the pair moved by the skew: ring time ran 5 s ahead of wall time
+    d_ring = syncs[1][EVT_TS] - syncs[0][EVT_TS]
+    d_wall = (syncs[1][EVT_ARGS]["time_ns"]
+              - syncs[0][EVT_ARGS]["time_ns"]) * 1e-9
+    assert d_ring - d_wall == pytest.approx(5.0, abs=0.05)
+
+
+def test_sim_sched_steps_trace_in_virtual_time():
+    """The simulator's scheduler steps are spans on the virtual clock."""
+    done, tr = _serve_traced()
+    steps = [e for e in tr.events() if e[EVT_NAME] == tr_ev.SCHED_STEP]
+    assert steps and all(e[EVT_PH] == "X" for e in steps)
+    t_hi = max(r.finish_s for r in done)
+    assert all(0.0 <= e[EVT_TS] <= e[EVT_TS] + e[EVT_DUR] <= t_hi + 1e-9
+               for e in steps)
+    assert sum(e[EVT_DUR] for e in steps) > 0     # virtual time advanced
+
+
+def test_span_opens_a_profiler_annotation_only_with_a_tracer(monkeypatch):
+    opened = []
+
+    class Ann:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(tr_ev, "_annotation", Ann)
+    assert get_tracer() is None
+    with tr_ev.span(tr_ev.ENGINE_STEP) as s:
+        pass
+    assert s is None and opened == []          # the shared no-op context
+    with tracing() as tr:
+        with tr_ev.span(tr_ev.ENGINE_STEP, track=tr_ev.TRACK_PIPELINE):
+            pass
+    assert opened == [tr_ev.ENGINE_STEP]
+    (e,) = tr.events()
+    assert (e[EVT_NAME], e[EVT_PH], e[EVT_TRACK]) == (
+        tr_ev.ENGINE_STEP, "X", tr_ev.TRACK_PIPELINE)
+
+
+def test_untraced_serve_makes_no_tracer_calls(monkeypatch, smoke_model):
+    """With no tracer installed, neither the simulator nor the real
+    decode path touches the tracer or the profiler."""
+    from repro.serving import EngineBackend, SamplerConfig
+
+    calls = []
+
+    def record(name):
+        return lambda *a, **k: calls.append(name)
+    for meth in ("_push", "span", "clock_sync", "now"):
+        monkeypatch.setattr(Tracer, meth, record(meth))
+    monkeypatch.setattr(tr_ev, "_annotation", record("annotation"))
+    assert get_tracer() is None
+    arrivals = cli_arrivals("bursty", 4, seed=0, prompt_len=8,
+                            max_new_tokens=3, gap_s=1.0, burst_size=2)
+    sched = ContinuousBatchingScheduler(_sim_backend(), SchedulerConfig())
+    assert all(not r.rejected
+               for r in sched.serve(requests_from_arrivals(arrivals)))
+    cfg, params = smoke_model
+    be = EngineBackend(cfg, params, engine=None, n_slots=2, max_len=32,
+                       sampler=SamplerConfig())
+    sched = ContinuousBatchingScheduler(be, SchedulerConfig())
+    done = sched.serve(requests_from_arrivals(
+        arrivals, vocab_size=cfg.vocab_size))
+    assert all(not r.rejected for r in done)
+    assert calls == []
