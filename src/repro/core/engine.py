@@ -604,8 +604,15 @@ class InterleavedEngine:
         PV = M.round_up(cfg.vocab_size, 256)
         prefetch = self.prefetch
 
-        layer_shapes = pspec.shapes(M.build_param_specs(cfg)["layers"])
+        layer_specs = M.build_param_specs(cfg)["layers"]
+        layer_shapes = pspec.shapes(layer_specs)
         is_sds = lambda x: isinstance(x, jax.ShapeDtypeStruct)
+        # one layer's 'model' layout in the resident store: a streamed
+        # layer is read in that layout too, so every row of a chunk
+        # partitions (and rounds) the same whichever store holds it
+        res_layouts = jax.tree.map(
+            lambda s: P(*self._res_pspec(s.shape[1:], s.axes[1:])[3:]),
+            layer_specs, is_leaf=pspec.is_spec)
         stage_dims = jax.tree.map(
             lambda s: stage_shard_dim(s.shape[1:], n_stage), layer_shapes,
             is_leaf=is_sds)
@@ -650,21 +657,6 @@ class InterleavedEngine:
             return jax.lax.ppermute(
                 x, ax, [(i, (i + 1) % n_stage) for i in range(n_stage)])
 
-        @_scope("chunk_params")
-        def chunk_params(res_local, fetched, s_d):
-            """Assemble the K (padded) layers of the active chunk on this
-            stage: resident cap first, then the streamed store (headroom +
-            streamed tail) — dead slots carry zero/identity layers."""
-            res_s = jax.tree.map(
-                lambda r: jax.lax.dynamic_index_in_dim(r[:, 0], s_d, 0,
-                                                       keepdims=False),
-                res_local)                        # (k_res_cap, ...)
-            if k_off_cap == 0 or fetched is None:
-                return res_s
-            return jax.tree.map(
-                lambda r, f: jnp.concatenate([r, f.astype(r.dtype)], axis=0),
-                res_s, fetched)
-
         step_mode = self.fetch_mode == "step"
 
         def step_fn(resident, offload, shared, cache, glob, tokens,
@@ -682,6 +674,25 @@ class InterleavedEngine:
             real_tab: (1, n_seg, K) bool — slot holds a real model layer
             (False on dead padding AND grid overhang past cfg.n_layers)."""
             d = jax.lax.axis_index(ax)
+
+            def layer_params(store, lead):
+                """One layer of every leaf of `store`, at index `lead`, read
+                in place by the layer scan, in the resident store's dtype
+                and 'model' layout: nothing assembles the active chunk,
+                since a sliced or concatenated stack that feeds a scan is
+                materialized."""
+                def one(w, r, layout):
+                    rest = w.shape[len(lead):]
+                    row = jax.lax.dynamic_slice(
+                        w, tuple(lead) + (0,) * len(rest),
+                        (1,) * len(lead) + rest).reshape(rest)
+                    row = row.astype(r.dtype)
+                    if all(p is None for p in layout):
+                        return row
+                    return jax.lax.with_sharding_constraint(
+                        row, NamedSharding(self.mesh, layout))
+                with _scope("chunk_params"):
+                    return jax.tree.map(one, store, resident, res_layouts)
             # dead-slot mask (DESIGN.md §13): resident slots past the live
             # boundary, unfilled headroom, and cap padding are identity —
             # zero weights make them so numerically, the mask makes it
@@ -736,11 +747,9 @@ class InterleavedEngine:
                     # self-draft: zero weight streaming — the whole point
                     nxt = cur = None
                 elif step_mode:
-                    nxt = None
-                    with _scope("restore"):
-                        cur = None if k_off_cap == 0 else jax.tree.map(
-                            lambda w: jax.lax.dynamic_index_in_dim(
-                                w[0], s_d, 0, False), offload)
+                    # the restored buffer (1, n_seg, k_off_cap, ...), read
+                    # in place at [0, s_d, j] by the streamed scan
+                    nxt, cur = None, offload
                 else:
                     nxt = fetch_chunk_weights(offload, tau + 1, d) \
                         if prefetch else None
@@ -748,21 +757,19 @@ class InterleavedEngine:
                         fetch_chunk_weights(offload, tau, d)
 
                 # entering micro-batches embed their token at chunk 0
-                tok_m = jnp.take(tokens, jnp.clip(m_d, 0, n_mb - 1), axis=0)
+                m_c = jnp.clip(m_d, 0, n_mb - 1)
+                tok_m = jnp.take(tokens, m_c, axis=0)
                 x_in = jnp.where((c_d == 0)[..., None, None],
                                  M.embed(shared, tok_m).astype(jnp.bfloat16),
                                  x)
 
-                p_chunk = chunk_params(resident, cur, s_d)
                 with _scope("cache_read"):
                     cache_chunk = {kk: jax.lax.dynamic_index_in_dim(
                         v[:, 0], s_d, 0, keepdims=False) for kk, v in
                         cache_l.items()}      # (k, n_mb, mb, ...)
                     cache_mb = {kk: jax.lax.dynamic_index_in_dim(
-                        v, jnp.clip(m_d, 0, n_mb - 1), 1, keepdims=False)
+                        v, m_c, 1, keepdims=False)
                         for kk, v in cache_chunk.items()}   # (k, mb, ...)
-                    if res_only:
-                        cache_mb = {kk: v[:KC] for kk, v in cache_mb.items()}
 
                 moe_mesh = self.mesh if (cfg.family == Family.MOE
                                          and "model" in self.mesh.shape) \
@@ -772,50 +779,76 @@ class InterleavedEngine:
                                        pos_ids, enc_len=self.enc_len,
                                        moe_mode="auto", q_slots=q_slots)
 
-                def body(carry, xs_l):
-                    # dead slots are identity: activation (and MoE aux)
-                    # pass through untouched; their cache writes land in
-                    # rows nothing ever reads
-                    x_prev, aux_prev = carry
-                    (x_new, aux_new), ys_l = inner(carry, xs_l)
-                    alive = xs_l["live"]
-                    return (jnp.where(alive, x_new, x_prev),
-                            jnp.where(alive, aux_new, aux_prev)), ys_l
+                window = jax.lax.dynamic_index_in_dim(win_d, s_d, 0, False)
+                live = live_d & jax.lax.dynamic_index_in_dim(real_d, s_d, 0,
+                                                             False)
 
+                def tier_body(lo, store, lead):
+                    """The scan body over rows lo + j of the chunk: the
+                    layer at `lead(j)` of its tier's store, and the
+                    chunk's row lo + j of the masks and the cache."""
+                    def body(carry, j):
+                        # dead slots are identity: activation (and MoE aux)
+                        # pass through untouched; their cache writes land
+                        # in rows nothing ever reads
+                        x_prev, aux_prev = carry
+                        row = lo + j
+                        xs_l = {kk: jax.lax.dynamic_index_in_dim(
+                            v, row, 0, False) for kk, v in cache_mb.items()}
+                        xs_l["window"], xs_l["live"] = (
+                            jax.lax.dynamic_index_in_dim(v, row, 0, False)
+                            for v in (window, live))
+                        xs_l["p"] = layer_params(store, lead(j))
+                        (x_new, aux_new), ys_l = inner(carry, xs_l)
+                        alive = xs_l["live"]
+                        return (jnp.where(alive, x_new, x_prev),
+                                jnp.where(alive, aux_new, aux_prev)), ys_l
+                    return body
+
+                # the chunk's rows run as two scans of one body, each
+                # reading its layers in place from the store that holds
+                # them: resident rows [0, k_res_cap) from the resident
+                # store, then streamed rows (headroom + streamed tail)
+                # [k_res_cap, K) from the restored buffer (step mode) or
+                # the fetched chunk (slot mode)
+                zero = jnp.int32(0)
+                tiers = [(0, k_res_cap, resident, lambda j: (s_d, zero, j))]
+                if cur is not None:
+                    tiers.append((k_res_cap, k_off_cap, cur,
+                                  (lambda j: (zero, s_d, j)) if step_mode
+                                  else (lambda j: (j,))))
+                carry = (x_in, jnp.float32(0.))
+                ys = []                               # (first row, ys)
                 with _scope("layers"):
-                    xs = {"p": p_chunk,
-                          "window": jax.lax.dynamic_index_in_dim(
-                              win_d, s_d, 0, False),
-                          "live": live_d & jax.lax.dynamic_index_in_dim(
-                              real_d, s_d, 0, False)}
-                    xs.update(cache_mb)
-                    (x_out, _), ys = jax.lax.scan(
-                        body, (x_in, jnp.float32(0.)), xs)
+                    for lo, n, store, lead in tiers:
+                        if n:
+                            carry, ys_t = jax.lax.scan(
+                                tier_body(lo, store, lead), carry,
+                                jnp.arange(n, dtype=jnp.int32))
+                            ys.append((lo, ys_t))
+                x_out = carry[0]
 
-                # commit cache only when valid
-                m_c = jnp.clip(m_d, 0, n_mb - 1)
-
-                def commit(old, new):
+                # commit cache only when valid, each scan's rows in place
+                # (a resident-only draft leaves the streamed rows untouched)
+                def commit(old, kk):
                     cur_s = jax.lax.dynamic_index_in_dim(old[:, 0], s_d, 0,
                                                          False)
                     prev = jax.lax.dynamic_index_in_dim(cur_s, m_c, 1, False)
-                    if res_only:
-                        # the draft scan produced KC rows: write them back
-                        # into the resident prefix, streamed rows untouched
-                        upd = jnp.where(valid, new.astype(old.dtype),
-                                        prev[:KC])
+                    upd = prev
+                    for lo, ys_t in ys:
+                        new = ys_t[kk]
+                        new = jnp.where(valid, new.astype(old.dtype),
+                                        prev[lo:lo + new.shape[0]])
                         upd = jax.lax.dynamic_update_slice_in_dim(
-                            prev, upd, 0, axis=0)
-                    else:
-                        upd = jnp.where(valid, new.astype(old.dtype), prev)
+                            upd, new, lo, axis=0)
                     cur_s = jax.lax.dynamic_update_index_in_dim(
                         cur_s, upd, m_c, 1)
                     return jax.lax.dynamic_update_index_in_dim(
                         old, cur_s[None], s_d, 0)
                 cache_l = dict(cache_l)      # keep read-only keys (xk/xv)
                 with _scope("cache_commit"):
-                    cache_l.update({kk: commit(cache_l[kk], ys[kk])
-                                    for kk in ys})
+                    cache_l.update({kk: commit(cache_l[kk], kk)
+                                    for kk in ys[0][1]})
 
                 # last chunk: unembed and stash logits
                 with _scope("unembed"):

@@ -4,6 +4,7 @@ a subprocess with a forced device count (the `run_worker` fixture)."""
 import importlib.util
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -120,3 +121,33 @@ def _run_worker(worker_src, *argv, devices=8, timeout=900):
 @pytest.fixture
 def run_worker():
     return _run_worker
+
+
+# ----------------------------------------------------------------------------
+# compiled-program shapes: the guard against the step program copying a
+# stack of layers' weights (the layer scan reads each layer in place)
+# ----------------------------------------------------------------------------
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?[a-z]\w*\[[^=]*?)\s"
+    r"([a-z][\w\-]*)\(", re.M)
+_HLO_ARRAY = re.compile(r"[a-z]\w*\[([\d,]*)\]")
+
+
+def hlo_results(hlo_text):
+    """(name, opcode, [dims of each array it yields]) of every
+    instruction of a compiled program's text."""
+    return [(name, opc, [tuple(int(d) for d in dims.split(",") if d)
+                         for dims in _HLO_ARRAY.findall(shape)])
+            for name, shape, opc in _HLO_INSTR.findall(hlo_text)]
+
+
+def matrix_leaf_shapes(layer_shapes):
+    """The per-layer leaf shapes of rank >= 2. A norm scale's stack, (n,
+    d_model), has the shape of n activation rows, so only the matrices —
+    all but a few KB of a layer — are guarded."""
+    return {tuple(s) for s in layer_shapes if len(s) >= 2}
+
+
+def is_layer_stack(dims, leaf_shapes):
+    """`dims` is a stack of >= 2 layers of one of `leaf_shapes`."""
+    return len(dims) >= 3 and dims[0] >= 2 and tuple(dims[1:]) in leaf_shapes

@@ -105,6 +105,56 @@ def test_stage_shard_dim_prefers_largest_divisible():
     assert stage_shard_dim((64, 64), 4) == 0
 
 
+@pytest.fixture(scope="module")
+def in_place_engine():
+    """One stage, 2 segments of 2 resident + 1 streamed layers, step
+    fetch mode, on the test process's one CPU device."""
+    import jax
+    import repro.core.engine as E
+    from repro.configs.base import Family, ModelConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models import model as M
+    cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=6, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                      head_dim=16)
+    eng = E.InterleavedEngine(cfg, make_mesh((1,), ("data",)),
+                              E.UniformPlan(1, 2, 2, 1), n_mb=1, mb=1,
+                              max_len=32, fetch_mode="step")
+    assert (eng.k_res_cap, eng.k_off_cap, eng.plan.n_seg) == (2, 1, 2)
+    return eng, eng.init_state(M.init_params(cfg, jax.random.PRNGKey(0)))
+
+
+@pytest.mark.parametrize("program", ["step", "verify", "draft"])
+def test_layer_scan_reads_weights_in_place(in_place_engine, program):
+    """The decode step (q_len 1), the verify step (q_len 2) and the
+    resident-only draft compile no copy of a stack of layers' weights:
+    each layer is read where its store holds it, so no instruction but a
+    parameter yields a multi-layer stack of a weight leaf."""
+    import jax
+    import jax.numpy as jnp
+    from conftest import hlo_results, is_layer_stack, matrix_leaf_shapes
+    eng, st = in_place_engine
+    q_len = 2 if program == "verify" else 1
+    tail = (st["shared"], st["cache"], st["glob"],
+            jnp.ones((1, 1, q_len), jnp.int32), eng._kl_dev, eng._win_dev,
+            eng._live_dev)
+    if program == "draft":
+        prog, args = eng._build_step(1, resident_only=True), \
+            (st["resident"],) + tail
+    else:
+        prog = eng._build_step(q_len)
+        args = (st["resident"], eng._fetch(st["offload"])) + tail
+    leaves = matrix_leaf_shapes(x.shape[3:]
+                                for x in jax.tree.leaves(st["resident"]))
+    assert leaves
+    results = hlo_results(prog.lower(*args).compile().as_text())
+    assert len(results) > 100
+    stacks = [(name, opc, dims) for name, opc, shapes in results
+              for dims in shapes
+              if opc != "parameter" and is_layer_stack(dims, leaves)]
+    assert not stacks, stacks[:8]
+
+
 MULTIPOD_WORKER = r"""
 import jax, jax.numpy as jnp, functools, sys
 jnp.bfloat16 = jnp.float32

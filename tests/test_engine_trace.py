@@ -68,11 +68,14 @@ def opcodes(program):
     text = program.lower(*args).compile().as_text()
     return collections.Counter(re.findall(
         r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*.*?\s([a-z][\w\-]*)\(", text,
-        re.M)), "lime." in text
+        re.M)), "lime." in text, text
 
-with_scopes, marked = opcodes(eng._build_step(1))
+with_scopes, marked, step_text = opcodes(eng._build_step(1))
+out["step_hlo"] = step_text
+out["resident_shapes"] = [list(x.shape) for x in
+                          jax.tree.leaves(st["resident"])]
 E._scope = lambda part: contextlib.contextmanager(lambda: (yield))()
-without, unmarked = opcodes(eng._build_step(1))
+without, unmarked, _ = opcodes(eng._build_step(1))
 out["ops_with"], out["ops_without"] = dict(with_scopes), dict(without)
 out["marked"], out["unmarked"] = marked, unmarked
 print("RESULT " + json.dumps(out))
@@ -125,13 +128,30 @@ def test_engine_spans_nest_from_the_scheduler_step_down(traced):
 
 
 def test_engine_scopes_map_step_ops_to_chunk_params(traced):
+    """Every mapped op is a `lime.*` part, the layer scan is one, and
+    what maps to the chunk's weights (`lime.chunk_params`, the per-layer
+    pick) or to `lime.restore` copies no stack of layers: the scan reads
+    each layer in place, so the part may compile to no op of its own."""
+    from conftest import hlo_results, is_layer_stack, matrix_leaf_shapes
+    from repro.core.engine import hlo_scopes
     by_module = {s["module"]: s for s in traced["scopes"]}
     assert set(by_module) == {"jit_step_fn", "jit_fetch_fn"}
     step = by_module["jit_step_fn"]["ops"]
     parts = set(step.values())
-    assert "lime.chunk_params" in parts and "lime.layers" in parts
+    assert "lime.layers" in parts
     assert all(p.startswith("lime.") for p in parts)
     assert set(by_module["jit_fetch_fn"]["ops"].values()) == {"lime.restore"}
+    # the step program the test compiled with its scopes is the one the
+    # tracer saw: every mapped op, under the same part
+    _, ops = hlo_scopes(traced["step_hlo"])
+    assert ops == step
+    leaves = matrix_leaf_shapes(s[3:] for s in traced["resident_shapes"])
+    weight_parts = {"lime.chunk_params", "lime.restore"}
+    stacks = [(name, dims) for name, _, shapes in
+              hlo_results(traced["step_hlo"])
+              if ops.get(name) in weight_parts
+              for dims in shapes if is_layer_stack(dims, leaves)]
+    assert not stacks, stacks
 
 
 def test_named_scopes_leave_the_compiled_step_as_it_was(traced):
