@@ -2,10 +2,15 @@
 
 BENCHMARK.json names each cell, its configuration, its traffic mix and
 the metrics it reports. The parts themselves are files found by those
-names, so a later change adds a cell, a configuration, a mix, a client
-kind or a metric by adding files and manifest entries only:
+names, so a later change adds a cell, a configuration, a model family,
+a mix, a client kind or a metric by adding files and manifest entries
+only:
 
-  configs/<config>.json     model sizes as run, with source and cuts
+  configs/<config>.json     model sizes as run, with source and cuts, and
+                            the name of the model's family
+  families/<family>.py      all that depends on the model's shape: its
+                            widths, ModelConfig, weights' layout,
+                            reference layers and counts (model.py)
   traffic/<traffic>.json    parameters the general generator reads
   workloads/<cell>.json     the cell's correctness limit and sample size
   clients/<kind>.py         a client kind (`make(traffic)`)
@@ -13,8 +18,10 @@ kind or a metric by adding files and manifest entries only:
 """
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Dict, List
 
@@ -49,15 +56,34 @@ def load_json(kind: str, name: str, root: Path = ROOT) -> dict:
 
 
 def load_module(kind: str, name: str, root: Path = ROOT):
-    path = Path(root) / kind / f"{name}.py"
+    """The module at <root>/<kind>/<name>.py, loaded once per process
+    and registered in sys.modules (a family's record finds its family
+    there by its type's module)."""
+    path = (Path(root) / kind / f"{name}.py").resolve()
     if not path.is_file():
         raise ManifestError(f"no {kind} module for {name!r} at {path}")
     mod_name = "chipbench_" + kind + "_" + "".join(
-        c if c.isalnum() else "_" for c in name)
+        c if c.isalnum() else "_" for c in name) + "_" + hashlib.sha1(
+            str(path).encode()).hexdigest()[:8]
+    if mod_name in sys.modules:
+        return sys.modules[mod_name]
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[mod_name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        sys.modules.pop(mod_name, None)
+        raise
     return mod
+
+
+def load_family(conf: dict, root: Path = ROOT):
+    """The family module that a configuration names."""
+    if "family" not in conf:
+        raise ManifestError(f"configuration {conf.get('name')!r} names no "
+                            f"family")
+    return load_module("families", conf["family"], root)
 
 
 def metrics_for(manifest: dict, cell: str, trace: bool) -> List[dict]:
@@ -70,15 +96,18 @@ def metrics_for(manifest: dict, cell: str, trace: bool) -> List[dict]:
 def resolve(cell: str, trace: bool, repo: Path = REPO,
             root: Path = ROOT) -> Dict[str, object]:
     """Everything a run of `cell` needs, loaded by name: its manifest
-    entry, configuration, traffic, cell file, client module and metric
-    readers. Raises ManifestError naming the first part not found."""
+    entry, configuration, family module, traffic, cell file, client module
+    and metric readers. Raises ManifestError naming the first part not
+    found."""
     manifest = load_manifest(repo)
     entry = cell_entry(manifest, cell)
+    config = load_json("configs", entry["config"], root)
     traffic = load_json("traffic", entry["traffic"], root)
     metrics = metrics_for(manifest, cell, trace)
     return {
         "entry": entry,
-        "config": load_json("configs", entry["config"], root),
+        "config": config,
+        "family": load_family(config, root),
         "traffic": traffic,
         "cell": load_json("workloads", cell, root),
         "client": load_module("clients", traffic["client"], root),

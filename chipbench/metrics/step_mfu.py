@@ -3,34 +3,22 @@ model needs for the work finished in the traced part of the window
 (prefill of each admitted prompt, and each decoded token at its live
 context), over that time, the chips and the chip's bf16 peak.
 
-Counted from the configuration's shapes: the matmuls of every layer, the
-LM head where logits are produced (every decoded token; a prompt's last
-position), and attention over the keys each query sees (causal in
-prefill). Padding slots, bubbles and recomputation are not model FLOPs."""
-
-
-def layer_matmul_flops(d) -> float:
-    """Per token per layer: q, k, v, o projections and the gated MLP."""
-    D, H, KV, dh, F = d.d_model, d.n_heads, d.n_kv_heads, d.head_dim, d.d_ff
-    return 2.0 * (D * H * dh + 2 * D * KV * dh + H * dh * D + 3 * D * F)
-
-
-def attn_flops(d, keys: float) -> float:
-    """Per query per layer: scores and the weighted sum over `keys`."""
-    return 4.0 * d.n_heads * d.head_dim * keys
+Counted from the configuration's shapes by its family
+(`families/<family>.py`): the matmuls of every layer (the active experts
+only, where there are experts), the LM head where logits are produced
+(every decoded token; a prompt's last position), and attention over the
+keys each query sees (causal in prefill). Padding slots, bubbles and
+recomputation are not model FLOPs."""
 
 
 def decode_flops(d, ctx: int) -> float:
     """One decoded token whose query sees ctx + 1 keys."""
-    return d.n_layers * (layer_matmul_flops(d) + attn_flops(d, ctx + 1)) \
-        + 2.0 * d.d_model * d.vocab
+    return d.family.decode_flops(d, ctx)
 
 
 def prefill_flops(d, n: int) -> float:
     """A prompt of n tokens, causal, logits at its last position."""
-    return d.n_layers * (n * layer_matmul_flops(d)
-                         + attn_flops(d, n * (n + 1) / 2.0)) \
-        + 2.0 * d.d_model * d.vocab
+    return d.family.prefill_flops(d, n)
 
 
 def window_flops(run, t0: float, t1: float) -> float:

@@ -1,11 +1,11 @@
 """The plain float32 reference that decides `correct`.
 
-A dense decoder as published (pre-RMSNorm, GQA attention with rotate-half
-RoPE, SwiGLU MLP, untied LM head), written here in jax.numpy at float32
-with `Precision.HIGHEST` matmuls, and run layer by layer over whole
-sequences so it fits beside what is left on the device. It imports
-nothing of the program and takes none of its arrays: the weights are
-regenerated from the seed (`weights.py`).
+The model as published, in jax.numpy at float32 with `Precision.HIGHEST`
+matmuls: the layers and the head are its family's (`families/<family>.py`
+`block` and `head`), run here layer by layer over whole sequences so it
+fits beside what is left on the device. It imports nothing of the
+program and takes none of its arrays: the weights are regenerated from
+the seed (`weights.py`).
 
 `forward_rows` runs the reference over each prompt followed by the tokens
 the program served, and returns the logits rows that predicted each
@@ -23,7 +23,6 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
-from chipbench.model import Dims
 from chipbench.weights import layer_f32, seed_key, shared_f32
 
 FP8_MAX = 448.0          # largest finite float8_e4m3fn
@@ -38,7 +37,7 @@ def _q8(a, axis=None):
     return (a / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _rope(x, theta: float):
+def rope(x, theta: float):
     """x: (S, heads, dh) at positions 0..S-1, rotate-half convention."""
     import jax.numpy as jnp
     S, _, dh = x.shape
@@ -49,69 +48,45 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _rms(x, offset, eps):
+def rms(x, offset, eps):
+    """RMSNorm with the weight stored as an offset from one."""
     import jax
     import jax.numpy as jnp
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * (1.0 + offset)
 
 
-@functools.lru_cache(maxsize=None)
-def _block(d: Dims, fp8: bool):
-    """One decoder layer over a whole sequence (S, D) -> (S, D)."""
+def _mm(fp8: bool):
+    """The linear map every family's layers go through: float32 at
+    `Precision.HIGHEST`, or for the control both operands rounded to fp8
+    (the activation per row, the weight per tensor)."""
     import jax
     import jax.numpy as jnp
     HI = jax.lax.Precision.HIGHEST
-    G = d.n_heads // d.n_kv_heads
 
     def mm(eq, a, w):
         if fp8:
-            a = _q8(a, axis=-1)
+            a, w = _q8(a, axis=-1), _q8(w)
         return jnp.einsum(eq, a, w, precision=HI)
-
-    @jax.jit
-    def block(x, W):
-        if fp8:
-            W = {k: (_q8(v) if v.ndim > 1 else v) for k, v in W.items()}
-        S = x.shape[0]
-        h = _rms(x, W["ln1"], d.norm_eps)
-        q = _rope(mm("sd,dhk->shk", h, W["attn/wq"]), d.rope_theta)
-        k = _rope(mm("sd,dhk->shk", h, W["attn/wk"]), d.rope_theta)
-        v = mm("sd,dhk->shk", h, W["attn/wv"])
-        qg = q.reshape(S, d.n_kv_heads, G, d.head_dim)
-        s = jnp.einsum("skgd,tkd->kgst", qg, k, precision=HI) \
-            * d.head_dim ** -0.5
-        causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-        s = jnp.where(causal, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgst,tkd->skgd", p, v, precision=HI)
-        o = o.reshape(S, d.n_heads, d.head_dim)
-        x = x + mm("shk,hkd->sd", o, W["attn/wo"])
-        h = _rms(x, W["ln2"], d.norm_eps)
-        g = jax.nn.silu(mm("sd,df->sf", h, W["mlp/wi_gate"])) \
-            * mm("sd,df->sf", h, W["mlp/wi_up"])
-        return x + mm("sf,fd->sd", g, W["mlp/wo"])
-    return block
+    return mm
 
 
 @functools.lru_cache(maxsize=None)
-def _head(d: Dims, fp8: bool):
+def _block(block, d, kind, fp8: bool):
+    """The family's `block` for one kind of layer, compiled."""
     import jax
-    import jax.numpy as jnp
-    HI = jax.lax.Precision.HIGHEST
-
-    @jax.jit
-    def head(x, rows, final_norm, lm_head):
-        h = _rms(x[rows], final_norm, d.norm_eps)
-        w = lm_head[:, :d.vocab]
-        if fp8:
-            h, w = _q8(h, axis=-1), _q8(w)
-        return jnp.einsum("sd,dv->sv", h, w, precision=HI)
-    return head
+    mm = _mm(fp8)
+    return jax.jit(lambda x, W: block(d, kind, x, W, mm))
 
 
-def forward_rows(d: Dims, seed: int, seqs: Sequence[Tuple[np.ndarray,
-                                                          np.ndarray]],
+@functools.lru_cache(maxsize=None)
+def _head(head, d, fp8: bool):
+    import jax
+    mm = _mm(fp8)
+    return jax.jit(lambda x, rows, shared: head(d, x[rows], shared, mm))
+
+
+def forward_rows(d, seed: int, seqs: Sequence[Tuple[np.ndarray, np.ndarray]],
                  length: int, *, control: bool = False):
     """seqs: (tokens, rows) pairs; each sequence is right-padded to
     `length` (causal attention keeps the pad out of earlier rows), and the
@@ -130,17 +105,17 @@ def forward_rows(d: Dims, seed: int, seqs: Sequence[Tuple[np.ndarray,
             pad = np.zeros(length, np.int32)
             pad[:len(toks)] = toks
             xs[fp8].append(sh["embedding"][jnp.asarray(pad)])
+    fam = d.family
     for layer in range(d.n_layers):
         W = layer_f32(d, key, layer)
         for fp8 in streams:
-            block = _block(d, fp8)
+            block = _block(fam.block, d, fam.layer_kind(d, layer), fp8)
             xs[fp8] = [block(x, W) for x in xs[fp8]]
         del W
     out = []
     for fp8 in streams:
-        head = _head(d, fp8)
-        out.append([np.asarray(head(x, jnp.asarray(rows, jnp.int32),
-                                    sh["final_norm"], sh["lm_head"]))
+        head = _head(fam.head, d, fp8)
+        out.append([np.asarray(head(x, jnp.asarray(rows, jnp.int32), sh))
                     for x, (_, rows) in zip(xs[fp8], seqs)])
     return out if control else out[0]
 

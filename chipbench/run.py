@@ -4,11 +4,14 @@
   python chipbench/run.py --workload <cell> --seed <n> --seconds <s> \
       --trace <0|1>
 
-One process on the TPU it is started on; it starts no children. It builds
-the server through `launch/serve.parse_args` / `resolve_stages` /
-`build_server` with `--impl pallas` (LimeServer -> ContinuousBatching-
-Scheduler -> EngineBackend -> InterleavedEngine), with weights it makes
-from the seed (`weights.py`). Set-up warms every prompt length of the
+One process on the TPU it is started on; it starts no children. The
+configuration's family (`families/<family>.py`) gives the model's shape:
+its widths, the program's ModelConfig, the weights' layout, the
+reference's layers and the counts. It builds the server through
+`launch/serve.parse_args` / `resolve_stages` / `build_server` with
+`--impl pallas` (LimeServer -> ContinuousBatchingScheduler ->
+EngineBackend -> InterleavedEngine), with weights it makes from the seed
+(`weights.py`). Set-up warms every prompt length of the
 cell's traffic, then the cell's clients drive the scheduler step by step
 on the wall clock; with a ramp, the window opens after that many requests
 have finished. The window lasts `--seconds`. Afterwards the tokens served
@@ -256,7 +259,6 @@ def run_cell(args, parts, devs, peak: dict, *, control: bool = False) -> dict:
     import numpy as np
 
     from chipbench import traffic as T, weights, window as W
-    from chipbench.model import dims, model_config
     from repro.launch import serve as S
     from repro.launch.compile_cache import compile_stats, enable_compile_cache
     from repro.serving import ContinuousBatchingScheduler, SchedulerConfig
@@ -269,8 +271,8 @@ def run_cell(args, parts, devs, peak: dict, *, control: bool = False) -> dict:
     # every program in the cache after the first run, however quick
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
-    d = dims(parts["config"])
-    cfg = model_config(d)
+    d = parts["family"].dims(parts["config"])
+    cfg = d.family.model_config(d)
     max_len = T.max_len(tr)
     t = time.monotonic()
     params = weights.program_params(d, seed)

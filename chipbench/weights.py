@@ -4,23 +4,18 @@ The benchmark makes the weights itself, hands them to the program in the
 program's parameter layout (`program_params`, one jitted call, bf16 as
 served), and regenerates the same values layer by layer for the
 reference (`layer_f32`, `shared_f32`), so the reference takes nothing the
-program has made. Every leaf is drawn from its own key, folded from the
-seed by leaf index and layer, so one layer can be drawn alone.
-
-Conventions of the program's layout that the reference must know:
-  * RMSNorm weights are stored as offsets from one (`x * (1 + s)`);
-  * attention weights are per head: wq (D, H, dh), wk/wv (D, KV, dh),
-    wo (H, dh, D); MLP wi_gate/wi_up (D, F), wo (F, D);
-  * the embedding (PV, D) and LM head (D, PV) are padded to PV rows.
+program has made. The leaves, their shapes and spreads and the stacks
+they sit in are the configuration's family's (`chipbench/model.py`).
+Every leaf is drawn from its own key: a shared leaf's from its index in
+sorted order, a layer leaf's from len(shared) + its index in its layer's
+sorted leaf set, folded with the global layer number. So one layer can
+be drawn alone.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
 
 import numpy as np
-
-from chipbench.model import Dims
 
 NORM_STD = 0.05        # spread of the stored norm offsets
 # Query gain: attention scores then spread with a standard deviation of
@@ -42,31 +37,6 @@ def seed_key(seed: int):
     return jnp.asarray(words, jnp.uint32)
 
 
-def layer_leaves(d: Dims) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    """Per-layer leaves: path -> (shape, std)."""
-    D, H, KV, dh, F = d.d_model, d.n_heads, d.n_kv_heads, d.head_dim, d.d_ff
-    return {
-        "ln1": ((D,), NORM_STD),
-        "attn/wq": ((D, H, dh), QK_GAIN * D ** -0.5),
-        "attn/wk": ((D, KV, dh), D ** -0.5),
-        "attn/wv": ((D, KV, dh), D ** -0.5),
-        "attn/wo": ((H, dh, D), (H * dh) ** -0.5),
-        "ln2": ((D,), NORM_STD),
-        "mlp/wi_gate": ((D, F), D ** -0.5),
-        "mlp/wi_up": ((D, F), D ** -0.5),
-        "mlp/wo": ((F, D), F ** -0.5),
-    }
-
-
-def shared_leaves(d: Dims) -> Dict[str, Tuple[Tuple[int, ...], float]]:
-    PV, D = d.padded_vocab, d.d_model
-    return {
-        "embedding": ((PV, D), 1.0),
-        "lm_head": ((D, PV), D ** -0.5),
-        "final_norm": ((D,), NORM_STD),
-    }
-
-
 def _draw(key, index: int, layer, shape, std):
     import jax
     import jax.numpy as jnp
@@ -86,65 +56,74 @@ def _nest(flat: dict) -> dict:
     return out
 
 
-def program_params(d: Dims, seed: int):
+def program_params(d, seed: int):
     """The whole parameter tree in the program's layout, bf16, made on
     the default device in one jitted call."""
     import jax
     import jax.numpy as jnp
+    fam = d.family
+    shared = sorted(fam.shared_leaves(d).items())
+    stacks = fam.stacks(d)
+    if sorted(l for r in stacks.values() for l in r) != list(
+            range(d.n_layers)):
+        raise ValueError(f"{d.name}: stacks {stacks} do not hold each of "
+                         f"the {d.n_layers} layers once")
+    for name, r in stacks.items():
+        if any(fam.layer_leaves(d, l) != fam.layer_leaves(d, r[0])
+               for l in r):
+            raise ValueError(f"{d.name}: the layers of stack {name!r} "
+                             f"differ in their leaves")
 
     def make(key):
-        flat = {}
-        for i, (path, (shape, std)) in enumerate(
-                sorted(shared_leaves(d).items())):
-            flat[path] = _draw(key, i, 0, shape, std)
-        base = len(shared_leaves(d))
-        layers = {}
-        for i, (path, (shape, std)) in enumerate(
-                sorted(layer_leaves(d).items())):
-            layers[path] = jax.vmap(
-                lambda l, i=i, shape=shape, std=std:
-                _draw(key, base + i, l, shape, std))(
-                    jnp.arange(d.n_layers))
-        flat.update({"layers/" + p: v for p, v in layers.items()})
+        flat = {path: _draw(key, i, 0, shape, std)
+                for i, (path, (shape, std)) in enumerate(shared)}
+        for name, r in stacks.items():
+            layers = jnp.arange(r.start, r.stop)
+            for i, (path, (shape, std)) in enumerate(
+                    sorted(fam.layer_leaves(d, r.start).items())):
+                flat[name + "/" + path] = jax.vmap(
+                    lambda l, i=i, shape=shape, std=std:
+                    _draw(key, len(shared) + i, l, shape, std))(layers)
         return _nest(flat)
 
     return jax.jit(make)(seed_key(seed))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_maker(d: Dims):
+def _layer_maker(base: int, leaves: tuple):
     import jax
     import jax.numpy as jnp
-    base = len(shared_leaves(d))
 
     @jax.jit
     def make(key, layer):
         return {path: _draw(key, base + i, layer, shape, std).astype(
                     jnp.float32)
-                for i, (path, (shape, std)) in enumerate(
-                    sorted(layer_leaves(d).items()))}
+                for i, (path, (shape, std)) in enumerate(leaves)}
     return make
 
 
 @functools.lru_cache(maxsize=None)
-def _shared_maker(d: Dims):
+def _shared_maker(leaves: tuple):
     import jax
     import jax.numpy as jnp
 
     @jax.jit
     def make(key):
         return {path: _draw(key, i, 0, shape, std).astype(jnp.float32)
-                for i, (path, (shape, std)) in enumerate(
-                    sorted(shared_leaves(d).items()))}
+                for i, (path, (shape, std)) in enumerate(leaves)}
     return make
 
 
-def layer_f32(d: Dims, key, layer: int) -> dict:
+def layer_f32(d, key, layer: int) -> dict:
     """One layer's weights as the program holds them, widened to f32."""
     import jax.numpy as jnp
-    return _layer_maker(d)(key, jnp.int32(layer))
+    fam = d.family
+    leaves = tuple(sorted(fam.layer_leaves(d, layer).items()))
+    return _layer_maker(len(fam.shared_leaves(d)), leaves)(
+        key, jnp.int32(layer))
 
 
-def shared_f32(d: Dims, key) -> dict:
-    """The embedding, LM head and final norm, widened to f32."""
-    return _shared_maker(d)(key)
+def shared_f32(d, key) -> dict:
+    """The embedding, final norm and LM head (if any), widened to f32."""
+    return _shared_maker(tuple(sorted(d.family.shared_leaves(d).items())))(
+        key)

@@ -11,7 +11,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from chipbench import manifest, window as W  # noqa: E402
-from chipbench.model import dims  # noqa: E402
+from chipbench.families.dense import dims  # noqa: E402
 
 PEAK = json.loads((ROOT / "chipbench" / "peaks.json").read_text())[
     "TPU v5 lite"]

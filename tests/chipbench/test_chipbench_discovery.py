@@ -1,5 +1,6 @@
-"""The chip benchmark finds every part of a cell by name, and a cell or a
-metric added as files and manifest entries only is picked up."""
+"""The chip benchmark finds every part of a cell by name, the model family
+of every configuration among them, and a cell or a metric added as files
+and manifest entries only is picked up."""
 import json
 import shutil
 import sys
@@ -35,6 +36,29 @@ def test_config_files_are_the_manifest_files():
         assert conf["name"] == c["name"]
         assert conf["source"] == c["source"]
         assert conf["reduced"] == c["reduced"]
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in MANIFEST["configs"]])
+def test_every_configs_family_resolves(config):
+    """A configuration names its family, whose module gives the record
+    of widths, and the record finds its way back to that module."""
+    conf = manifest.load_json("configs", config)
+    fam = manifest.load_family(conf)
+    d = fam.dims(conf)
+    assert d.family is fam and d.name == config
+    for part in ("model_config", "shared_leaves", "stacks", "layer_leaves",
+                 "layer_kind", "block", "head", "decode_flops",
+                 "prefill_flops", "kernel_layers"):
+        assert callable(getattr(fam, part)), part
+
+
+def test_a_missing_family_is_named(tmp_path):
+    conf = manifest.load_json("configs", MANIFEST["configs"][0]["name"])
+    with pytest.raises(manifest.ManifestError, match="names no family"):
+        manifest.load_family({k: v for k, v in conf.items()
+                              if k != "family"})
+    with pytest.raises(manifest.ManifestError, match="no_such_family"):
+        manifest.load_family(dict(conf, family="no_such_family"))
 
 
 def test_a_cell_and_a_metric_added_as_files_are_found(tmp_path):

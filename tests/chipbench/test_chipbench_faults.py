@@ -45,7 +45,9 @@ def _no_persistent_cache(monkeypatch):
 
 def _run(check=None, control=False):
     import jax
-    parts = {"entry": {"chips": 1}, "config": SMALL, "traffic": TRAFFIC,
+    parts = {"entry": {"chips": 1}, "config": SMALL,
+             "family": manifest.load_module("families", "dense"),
+             "traffic": TRAFFIC,
              "cell": {"check": check or dict(CELL["check"], min_tokens=10)},
              "client": manifest.load_module("clients", "closed_loop"),
              "metrics": []}

@@ -10,7 +10,7 @@ import pytest
 sys.path[:0] = [str(Path(__file__).resolve().parents[2])]
 
 from chipbench import reference, weights  # noqa: E402
-from chipbench.model import dims, model_config  # noqa: E402
+from chipbench.families.dense import dims, model_config  # noqa: E402
 
 SMALL = {"name": "small", "hidden_size": 128, "num_hidden_layers": 3,
          "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
