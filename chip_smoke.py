@@ -37,6 +37,7 @@ The compile cache is `$JAX_COMPILATION_CACHE_DIR` when set, else
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import sys
@@ -77,31 +78,36 @@ def parse_args(argv=None):
 class LogitsRecorder:
     """Wraps one backend so every emitted token's logits row is kept, per
     request, in emission order (row j is what output[j] was sampled
-    from). Rows stay on the device until compare() reads them."""
+    from). Rows stay on the device until compare() reads them. A decode
+    step may be sampled a call before its ids are returned (the backend's
+    look-ahead), so sampled rows queue until their step is returned."""
 
     def __init__(self, backend):
         self.rows = {}
         self._slot_rid = {}
-        self._last = None
+        self._sampled = collections.deque()
         V = backend.cfg.vocab_size
         sample, start, decode = (backend._sample, backend.start_batch,
                                  backend.decode_active)
 
         def _sample(logits):
-            self._last = logits[:, :V]
+            self._sampled.append(logits[:, :V])
             return sample(logits)
 
         def start_batch(reqs):
+            self._sampled.clear()        # a step in flight nobody reads
             out = start(reqs)
+            last = self._sampled.popleft()
             self._slot_rid = {i: r.rid for i, r in enumerate(reqs)}
             for i, r in enumerate(reqs):
-                self.rows.setdefault(r.rid, []).append(self._last[i])
+                self.rows.setdefault(r.rid, []).append(last[i])
             return out
 
-        def decode_active(slots):
-            out = decode(slots)
+        def decode_active(slots, more=()):
+            out = decode(slots, more)
+            last = self._sampled.popleft()
             for s in slots:
-                self.rows[self._slot_rid[s]].append(self._last[s])
+                self.rows[self._slot_rid[s]].append(last[s])
             return out
 
         backend._sample = _sample

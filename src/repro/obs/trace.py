@@ -52,7 +52,8 @@ and step / substrate internals (tracks "pipeline", "dev:<i>",
   engine.prefill  engine.decode  engine.verify  engine.draft
   engine.retier  engine.init_state  engine.seed_cache  engine.dispatch
   engine.fetch  engine.step  engine.scopes  backend.prefill
-  backend.sample  backend.sync  sched.step  hbm.bytes_in_use  clock.sync
+  backend.sample  backend.sync  backend.ahead  sched.step
+  hbm.bytes_in_use  clock.sync
 
 The chip path's spans nest, per scheduler step (track "pipeline"):
 
@@ -61,9 +62,16 @@ The chip path's spans nest, per scheduler step (track "pipeline"):
       backend.prefill  engine.init_state  engine.seed_cache
       backend.sample  backend.sync
     engine.decode (EngineBackend.decode_active)
-      engine.dispatch (InterleavedEngine.decode_requests)
-        engine.fetch  engine.step
-      backend.sample  backend.sync
+      engine.dispatch (InterleavedEngine.decode_requests)  this step,
+        engine.fetch  engine.step                          unless the last
+      backend.sample                                       call sent it
+      engine.dispatch  backend.sample      the next step, sent ahead
+      backend.sync                         this step's ids
+
+`backend.ahead` (counter, at the end of each `engine.decode` and when an
+admission drops a step in flight) carries the backend's cumulative decode
+steps dispatched, those dispatched before the host read the previous
+step's ids, and those whose ids nobody read.
 
 `engine.scopes` (one per compiled step program) maps each HLO instruction
 of the program to the innermost `lime.<part>` named scope of the engine's
@@ -131,6 +139,7 @@ ENGINE_SCOPES = "engine.scopes"
 BACKEND_PREFILL = "backend.prefill"
 BACKEND_SAMPLE = "backend.sample"
 BACKEND_SYNC = "backend.sync"
+BACKEND_AHEAD = "backend.ahead"
 SCHED_STEP = "sched.step"
 HBM_BYTES_IN_USE = "hbm.bytes_in_use"
 CLOCK_SYNC = "clock.sync"

@@ -22,7 +22,11 @@ implementations):
   kv_budget_tokens() fleet KV capacity in tokens, or None (unbounded)
   start_batch(reqs)  admit an idle-state batch; returns first token per
                      request (None where the substrate has no real tokens)
-  decode_active(slots) one decode step; {slot: token-or-None} per live slot
+  decode_active(slots, more)
+                     one decode step; {slot: token-or-None} per live slot.
+                     `more`: the slots that still want a token after this
+                     one (the scheduler's rule); a backend may dispatch
+                     their next step before it returns this one's
   join(slot, req)    mid-flight admission (only if can_join_running)
   release(slot)      slot freed by the scheduler
 """
@@ -431,7 +435,7 @@ class SimBackend:
             self._depth.note_round(k, acc)
         return committed
 
-    def decode_active(self, slots: Sequence[int]):
+    def decode_active(self, slots: Sequence[int], more: Sequence[int] = ()):
         if not slots:
             return {}
         ctx = max(self._ctx[s] for s in slots)
@@ -609,6 +613,11 @@ class EngineBackend:
         self._skew = 0.0
         self._state = None
         self._cur = None                      # (batch_width, 1) last tokens
+        # one-step look-ahead (DESIGN.md §9): the decode step dispatched
+        # before the host read its predecessor's ids, as (ids, the slots
+        # it decodes); the ids stay on the device until read
+        self._ahead = None
+        self._ahead_n = {"steps": 0, "ahead": 0, "discarded": 0}
 
     # -- clock -------------------------------------------------------------------
     def now(self) -> float:
@@ -684,6 +693,7 @@ class EngineBackend:
         live engine state (counter-only between epochs — init_state builds
         the demoted layout). Returns HBM bytes freed (< 0 on promote)."""
         eng = self.engine
+        self._drain()
         before = eng.demoted(stage)
         self._state, freed = eng.retier(self._state, stage, delta)
         moved = abs(eng.demoted(stage) - before)
@@ -941,6 +951,11 @@ class EngineBackend:
 
     def start_batch(self, reqs: Sequence) -> List[Optional[int]]:
         tr = get_tracer()
+        if self._ahead is not None:           # a step nobody will read
+            self._ahead = None
+            self._ahead_n["discarded"] += 1
+            if tr is not None:
+                self._note_ahead(tr)
         if tr is None:
             return self._start_batch(reqs, None)
         args = {"batch": len(reqs)}
@@ -1024,7 +1039,12 @@ class EngineBackend:
         self._note_hbm()
         return first
 
-    def decode_active(self, slots: Sequence[int]):
+    def decode_active(self, slots: Sequence[int], more: Sequence[int] = ()):
+        """One decode step for `slots`: {slot: token}. `more`, the slots
+        the scheduler says still want a token after this one, get their
+        next step dispatched before this step's ids are read, so the
+        host's read and its work up to the next call run under the
+        device's next step (DESIGN.md §9)."""
         # speculative round when a draft fits before the cache/ring end
         # (the last position is reserved for the committed-token write)
         if self.spec is not None:
@@ -1036,20 +1056,53 @@ class EngineBackend:
                 return self._decode_active_spec(slots, k)
         tr = get_tracer()
         if tr is None:
-            return self._decode_once(slots)
+            return self._decode_once(slots, more)
         with tr.span(tr_ev.ENGINE_DECODE, track=tr_ev.TRACK_PIPELINE,
                      args={"slots": len(slots)}):
-            return self._decode_once(slots)
+            out = self._decode_once(slots, more)
+            self._note_ahead(tr)
+            return out
 
-    def _decode_once(self, slots: Sequence[int]):
+    def _note_ahead(self, tr) -> None:
+        tr.counter(tr_ev.BACKEND_AHEAD, track=tr_ev.TRACK_PIPELINE,
+                   **self._ahead_n)
+
+    def _decode_once(self, slots: Sequence[int], more: Sequence[int]):
+        if self._ahead is None:
+            tok = self._dispatch(slots)
+        else:
+            tok, live = self._ahead
+            self._ahead = None
+            if not live.issuperset(slots):
+                raise RuntimeError(
+                    f"decode step for slots {sorted(slots)}, but the step "
+                    f"in flight decodes {sorted(live)}")
+        # speculative decoding proposes from the host's copy of the ids,
+        # so with it the next step waits for this one's read
+        if more and self.spec is None:
+            self._ahead = (self._dispatch(more), frozenset(more))
+            self._ahead_n["ahead"] += 1
+        with tr_ev.span(tr_ev.BACKEND_SYNC, track=tr_ev.TRACK_PIPELINE):
+            ids = np.asarray(tok)
+        if self.prefix_cache:
+            for s in slots:
+                self._slot_out[s].append(int(ids[s]))
+        if self.spec is not None:             # keep drafts in sync on the
+            for s in slots:                   # non-spec fallback step
+                self._ctl.observe(s, [int(ids[s])])
+        return {s: int(ids[s]) for s in slots}
+
+    def _dispatch(self, slots: Sequence[int]):
+        """Dispatch one decode step for `slots` and its sampling. Returns
+        the (batch_width,) sampled ids, on the device with their copy to
+        the host started; the next step's input is built from them there."""
         import jax.numpy as jnp
-        pipe = tr_ev.TRACK_PIPELINE
         active = np.zeros(self.batch_width, bool)
-        for s in slots:
-            active[s] = True
+        active[list(slots)] = True
+        active = jnp.asarray(active)
         if self.engine is not None:
             lg, self._state = self.engine.decode_requests(
-                self._state, self._cur, jnp.asarray(active))
+                self._state, self._cur, active)
         elif self.paged:
             if self.prefix_cache:
                 self._ensure_room(self._paged_cache, 1)
@@ -1059,20 +1112,25 @@ class EngineBackend:
                                            self._cur)
             if lg.ndim == 3:
                 lg = lg[:, 0]
-        with tr_ev.span(tr_ev.BACKEND_SAMPLE, track=pipe):
+        with tr_ev.span(tr_ev.BACKEND_SAMPLE, track=tr_ev.TRACK_PIPELINE):
             tok = self._sample(lg)
-        if self.prefix_cache:
-            for s in slots:
-                self._slot_out[s].append(int(tok[s]))
-        if self.spec is not None:             # keep drafts/pos in sync on
-            self._pos += 1                    # the non-spec fallback step
-            for s in slots:
-                self._ctl.observe(s, [int(tok[s])])
+        # one copy of the whole array, started now: read after the next
+        # step is dispatched, an eager per-slot index would queue behind it
+        tok.copy_to_host_async()
+        if self.spec is not None:
+            self._pos += 1
         # freed slots keep replaying their last token as pipeline padding
-        self._cur = jnp.where(jnp.asarray(active)[:, None], tok[:, None],
-                              self._cur)
-        with tr_ev.span(tr_ev.BACKEND_SYNC, track=pipe):
-            return {s: int(tok[s]) for s in slots}
+        self._cur = jnp.where(active[:, None], tok[:, None], self._cur)
+        self._ahead_n["steps"] += 1
+        return tok
+
+    def _drain(self) -> None:
+        """Read the ids of a step in flight (the next decode_active still
+        returns them), so what changes the state between steps acts after
+        every step the host has dispatched is accounted for."""
+        if self._ahead is not None:
+            tok, live = self._ahead
+            self._ahead = (np.asarray(tok), live)
 
     def _decode_active_spec(self, slots: Sequence[int], k: int):
         """One speculative round: propose k per live slot, verify all of

@@ -780,7 +780,12 @@ class ContinuousBatchingScheduler:
                     work[slot] = ("decode",)
             emitted = self._mixed(work)
         else:
-            emitted = self.backend.decode_active(sorted(active))
+            # the slots that want a token after this one (the finish
+            # rule below): the backend may dispatch their next step
+            # before it reads this one's
+            more = [s for s in sorted(active)
+                    if active[s].generated + 1 < active[s].max_new_tokens]
+            emitted = self.backend.decode_active(sorted(active), more)
         t = self.backend.now()
         for slot, toks in emitted.items():
             r = active.get(slot)

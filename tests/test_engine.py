@@ -4,6 +4,7 @@ The engine needs >= 4 devices; this module re-execs its worker in a
 subprocess with a forced host device count (the only sanctioned way to get
 multiple CPU devices without polluting the whole test session's jax state).
 """
+import json
 import os
 import subprocess
 import sys
@@ -334,3 +335,141 @@ def test_engine_lossless_ring_buffer_long_mode():
     sys.stdout.write(r.stdout)
     sys.stderr.write(r.stderr[-2000:])
     assert r.returncode == 0
+
+
+# ----------------------------------------------------------------------------
+# one-step look-ahead (DESIGN.md §9): each decode step is dispatched before
+# the host reads the previous step's ids, and the served tokens stay the
+# greedy continuation
+# ----------------------------------------------------------------------------
+AHEAD_WORKER = r"""
+import json
+import jax, jax.numpy as jnp, numpy as np
+jnp.bfloat16 = jnp.float32   # fp32 => the engine's tokens are exact
+import repro.core.engine as E
+from repro.configs.base import Family, ModelConfig
+from repro.launch.mesh import make_mesh
+from repro.models import model as M
+from repro.obs import trace as tr_ev
+from repro.serving import (ContinuousBatchingScheduler, EngineBackend,
+                           Request, SamplerConfig, SchedulerConfig)
+
+cfg = ModelConfig(name="d", family=Family.DENSE, n_layers=8, d_model=64,
+                  n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                  head_dim=16)
+params = jax.tree.map(
+    lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a,
+    M.init_params(cfg, jax.random.PRNGKey(0)))
+n = len(jax.devices())
+mesh = make_mesh((n,), ("data",))
+fwd = jax.jit(lambda t: M.forward(cfg, params, t)[0])
+
+
+def engine(n_mb=1, **kw):
+    return E.InterleavedEngine(cfg, mesh, E.UniformPlan(n, 2, 4 // n - 1, 1),
+                               n_mb=n_mb, mb=1, max_len=32, **kw)
+
+
+def greedy(prompt, n_new):
+    seq, out = list(prompt), []
+    for _ in range(n_new):
+        t = int(jnp.argmax(fwd(jnp.asarray([seq], jnp.int32))
+                           [0, -1, :cfg.vocab_size]))
+        out.append(t)
+        seq.append(t)
+    return out
+
+
+def prompt(i):
+    return np.random.default_rng(i).integers(1, 256, 8).astype(np.int32)
+
+
+def counts(tr):
+    c = [e[5] for e in tr.events() if e[0] == tr_ev.BACKEND_AHEAD]
+    return c[-1] if c else None
+
+
+def serve(be, max_new):
+    with tr_ev.tracing() as tr:
+        sched = ContinuousBatchingScheduler(be, SchedulerConfig())
+        done = sched.serve([Request(i, prompt(i), m, arrival_s=0.0)
+                            for i, m in enumerate(max_new)])
+    done = sorted(done, key=lambda r: r.rid)
+    return {"max_new": max_new, "tokens": [list(r.output) for r in done],
+            "greedy": [greedy(prompt(r.rid), r.max_new_tokens)
+                       for r in done],
+            "counts": counts(tr)}
+
+
+def backend(eng, n_slots=1):
+    return EngineBackend(cfg, params, engine=eng, n_slots=n_slots,
+                         max_len=32, sampler=SamplerConfig())
+
+
+out = {"engine": serve(backend(engine()), [1, 2, 5]),
+       "paged_engine": serve(backend(engine(paged=True, page_size=8)),
+                             [1, 2, 5])}
+# a step in flight when the tier boundary moves, and one dropped by the
+# next admission
+be = backend(engine(retier_headroom=1))
+with tr_ev.tracing() as tr:
+    first = be.start_batch([Request(0, prompt(0), 5)])
+    toks = first + [be.decode_active([0], [0])[0]]
+    freed = be._apply_retier(0, 1)
+    toks += [be.decode_active([0], [0])[0], be.decode_active([0], [0])[0]]
+    be.start_batch([Request(1, prompt(1), 5)])
+out["retier"] = {"tokens": toks, "greedy": greedy(prompt(0), 4),
+                 "freed": freed, "counts": counts(tr)}
+if n == 1:
+    out["no_engine"] = serve(backend(None), [1, 2, 5])
+else:
+    # the bursty layout: two slots in one epoch; the freed slot rides as
+    # padding while the other's steps go on being dispatched ahead
+    out["two_slots"] = serve(backend(engine(n_mb=2), n_slots=2), [2, 5, 1])
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["1dev", "2dev"])
+def ahead_run(request):
+    from conftest import _run_worker
+    r = _run_worker(AHEAD_WORKER, devices=request.param)
+    assert r.returncode == 0, r.stderr[-2000:]
+    line = [x for x in r.stdout.splitlines() if x.startswith("RESULT ")]
+    return json.loads(line[-1][len("RESULT "):])
+
+
+@pytest.mark.subprocess
+def test_lookahead_serves_the_greedy_forward_tokens(ahead_run):
+    assert {"engine", "paged_engine"} <= set(ahead_run)
+    for name, run in ahead_run.items():
+        assert run["tokens"] == run["greedy"], name
+
+
+@pytest.mark.subprocess
+def test_lookahead_dispatches_all_but_each_first_step_ahead(ahead_run):
+    """Back to back, one request at a time: a request decodes max_new - 1
+    steps, each but its first dispatched before the previous step's ids
+    were read, and none is dispatched past the request's last token."""
+    for name in ("engine", "paged_engine", "no_engine"):
+        if name not in ahead_run:
+            continue
+        max_new = ahead_run[name]["max_new"]
+        steps = sum(m - 1 for m in max_new)
+        assert ahead_run[name]["counts"] == {
+            "steps": steps, "ahead": steps - sum(m >= 2 for m in max_new),
+            "discarded": 0}, name
+    if "two_slots" in ahead_run:
+        # epochs (2, 5) and (1): 4 steps, the last 3 ahead
+        assert ahead_run["two_slots"]["counts"] == {
+            "steps": 4, "ahead": 3, "discarded": 0}
+
+
+@pytest.mark.subprocess
+def test_lookahead_survives_a_retier_and_counts_a_dropped_step(ahead_run):
+    """A retier between steps first reads the step in flight; the next
+    admission drops the step nobody will read, and counts it."""
+    run = ahead_run["retier"]
+    assert run["freed"] > 0
+    assert run["tokens"] == run["greedy"]
+    assert run["counts"] == {"steps": 4, "ahead": 3, "discarded": 1}

@@ -127,6 +127,29 @@ def test_engine_spans_nest_from_the_scheduler_step_down(traced):
     assert sum(s[0] == "engine.prefill" for s in spans) == 2 and n_dec == 6
 
 
+def test_next_decode_step_is_dispatched_before_the_read(traced):
+    """Per request: its first decode step dispatches itself and the next,
+    each later step but the last dispatches the next, and the last none;
+    each next step's dispatch starts before this step's read of its ids
+    ends (DESIGN.md §9)."""
+    spans = traced["spans"]
+    epochs = []
+    for step in sorted(s for s in spans if s[0] == "sched.step"):
+        def kids(name):
+            return sorted(s for s in spans
+                          if s[0] == name and _inside(s, step))
+        if kids("engine.prefill"):
+            epochs.append([])
+        elif kids("engine.decode"):
+            epochs[-1].append((kids("engine.dispatch"),
+                               kids("backend.sync")))
+    assert len(epochs) == 2
+    for epoch in epochs:
+        assert [len(d) for d, _ in epoch] == [2, 1, 0]
+        for dispatches, (sync,) in epoch[:-1]:
+            assert dispatches[-1][1] < sync[2]
+
+
 def test_engine_scopes_map_step_ops_to_chunk_params(traced):
     """Every mapped op is a `lime.*` part, the layer scan is one, and
     what maps to the chunk's weights (`lime.chunk_params`, the per-layer
